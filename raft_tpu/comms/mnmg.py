@@ -1,6 +1,6 @@
 """Multi-node-multi-device algorithms — the OPMG pattern over a mesh.
 
-Analog of the reference's MNMG consumers (SURVEY.md §2 parallelism taxonomy
+Analog of the reference's MNMG consumers (SURVEY.md §2 parallelism kinds
 #3): data pre-partitioned across workers, each runs the single-device
 primitive on its shard, results combined with communicator collectives —
 kNN via local top-k + allgather + ``knn_merge_parts``

@@ -116,7 +116,7 @@ def ring_pairwise_distance(
     """Distributed full distance matrix with both operands row-sharded:
     y-shards rotate around the ring; each device fills its (m/P, n) row
     block column-stripe by column-stripe (the 2D-blocked "tensor parallel"
-    analog of the distance matrix, SURVEY.md §2 taxonomy #4).
+    analog of the distance matrix, SURVEY.md §2 classification #4).
 
     Returns the (m, n) matrix row-sharded over the mesh.
     """

@@ -3,15 +3,13 @@ version-sensitive JAX APIs.
 
 JAX moves symbols between releases (``jax.experimental.shard_map.shard_map``
 graduated to ``jax.shard_map``; ``jax.tree_map`` was removed in favour of
-``jax.tree.map``; ``shard_map``'s replication-check kwarg was renamed
-``check_rep`` → ``check_vma``). Direct use of any spelling pins the codebase
-to one JAX release and is exactly the hazard that broke the seed suite
-(``jax.shard_map`` does not exist on JAX 0.4.x). This module resolves each
-symbol against the installed JAX at import time, from a declarative
-:data:`COMPAT_TABLE` that the static analyzer (``raft_tpu.analysis``, rule
-``api-compat``) consumes to flag direct spellings at lint time. The analog
-in the reference RAFT is the pinned-RAPIDS-version dependency wall; here the
-wall is one table.
+``jax.tree.map``). Direct use of any spelling pins the codebase to one JAX
+release. This module resolves each symbol against the installed JAX (0.9,
+``pyproject.toml``) at import time, from a declarative :data:`COMPAT_TABLE`
+that the static analyzer (``raft_tpu.analysis``, rule ``api-compat``)
+consumes to flag direct spellings at lint time. The analog in the reference
+RAFT is the pinned-RAPIDS-version dependency wall; here the wall is one
+table.
 
 Policy (enforced by ``python -m raft_tpu.analysis``):
 
@@ -28,7 +26,6 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-import inspect
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -36,7 +33,6 @@ import jax
 __all__ = [
     "COMPAT_TABLE",
     "CompatEntry",
-    "jax_version",
     "resolve",
     "shard_map",
     "axis_size",
@@ -66,37 +62,28 @@ class CompatEntry:
 COMPAT_TABLE: Tuple[CompatEntry, ...] = (
     CompatEntry(
         name="shard_map",
-        candidates=(
-            "jax.shard_map",
-            "jax.experimental.shard_map.shard_map",
-        ),
+        candidates=("jax.shard_map",),
         banned=(
             "jax.shard_map",
             "jax.experimental.shard_map.shard_map",
             "jax.experimental.shard_map",
         ),
-        reason="graduated from jax.experimental.shard_map in JAX 0.6; the "
-               "replication-check kwarg is check_rep on 0.4/0.5 and "
-               "check_vma on 0.6+ — compat.shard_map accepts either",
+        reason="graduated from jax.experimental.shard_map in JAX 0.6 (its "
+               "replication-check kwarg is check_vma); route through "
+               "compat so the next move is a one-line table edit",
     ),
     CompatEntry(
         name="axis_size",
-        candidates=(
-            "jax.lax.axis_size",
-            "jax.core.axis_frame",   # 0.4.x: returns the static size directly
-        ),
+        candidates=("jax.lax.axis_size",),
         banned=(
             "jax.lax.axis_size",
         ),
-        reason="lax.axis_size only exists on newer JAX; 0.4.x exposes the "
-               "static mesh-axis size via jax.core.axis_frame",
+        reason="static mesh-axis size inside a traced region; its home "
+               "has moved before (jax.core.axis_frame on 0.4)",
     ),
     CompatEntry(
         name="tree_map",
-        candidates=(
-            "jax.tree.map",
-            "jax.tree_util.tree_map",
-        ),
+        candidates=("jax.tree.map",),
         banned=(
             "jax.tree_map",
             "jax.tree_multimap",
@@ -105,22 +92,17 @@ COMPAT_TABLE: Tuple[CompatEntry, ...] = (
     ),
     CompatEntry(
         name="register_dataclass",
-        candidates=(
-            "jax.tree_util.register_dataclass",
-        ),
+        candidates=("jax.tree_util.register_dataclass",),
         banned=(
             "jax.tree_util.register_dataclass",
         ),
-        reason="added in JAX 0.4.26 and its signature is still evolving "
-               "(drop_fields, auto field inference); route through compat "
-               "so a shim has one place to land",
+        reason="its signature is still evolving (drop_fields, auto field "
+               "inference); route through compat so a shim has one place "
+               "to land",
     ),
     CompatEntry(
         name="pure_callback",
-        candidates=(
-            "jax.pure_callback",
-            "jax.experimental.pure_callback",
-        ),
+        candidates=("jax.pure_callback",),
         banned=(
             "jax.experimental.pure_callback",
         ),
@@ -131,27 +113,20 @@ COMPAT_TABLE: Tuple[CompatEntry, ...] = (
         name="compilation_cache_reset",
         candidates=(
             "jax.experimental.compilation_cache.compilation_cache.reset_cache",
-            "jax._src.compilation_cache.reset_cache",
         ),
         banned=(
             "jax.experimental.compilation_cache.compilation_cache",
             "jax._src.compilation_cache",
         ),
-        reason="the persistent-cache enable decision is memoized at the "
-               "first compile (is_cache_used); enabling the cache after "
-               "any jit has run requires reset_cache(), which lives under "
-               "experimental/_src — route through compat so the spelling "
-               "has one home (core/resources.py enable_compilation_cache)",
+        reason="JAX opens the persistent cache once per process and keeps "
+               "its directory; switching directories (core/resources.py "
+               "enable_compilation_cache) needs reset_cache(), which lives "
+               "under experimental — route through compat so the spelling "
+               "has one home",
     ),
     CompatEntry(
         name="io_callback",
-        candidates=(
-            # forward candidate: resolution is eager at import, so the
-            # anticipated graduation must already be in the list or the
-            # whole library stops importing on that future JAX
-            "jax.io_callback",
-            "jax.experimental.io_callback",
-        ),
+        candidates=("jax.experimental.io_callback",),
         banned=(
             "jax.experimental.io_callback",
         ),
@@ -159,17 +134,6 @@ COMPAT_TABLE: Tuple[CompatEntry, ...] = (
                "eventual graduation is a one-line table edit",
     ),
 )
-
-
-def jax_version() -> Tuple[int, ...]:
-    """Installed JAX version as a comparable int tuple (e.g. (0, 4, 37))."""
-    parts = []
-    for p in jax.__version__.split("."):
-        digits = "".join(ch for ch in p if ch.isdigit())
-        if not digits:
-            break
-        parts.append(int(digits))
-    return tuple(parts)
 
 
 def _lookup(dotted: str) -> Any:
@@ -217,22 +181,13 @@ def resolve(name: str) -> Any:
 
 _shard_map_impl: Callable = resolve("shard_map")
 
-# 0.4/0.5 call the replication check `check_rep`; 0.6+ renamed it
-# `check_vma`. Detect which one the resolved implementation takes.
-_sm_params = frozenset(inspect.signature(_shard_map_impl).parameters)
-_SHARD_MAP_CHECK_KW = "check_vma" if "check_vma" in _sm_params else "check_rep"
-
 
 def shard_map(f, *, mesh, in_specs, out_specs,
               check_vma: Optional[bool] = None, **kwargs):
-    """``shard_map`` across JAX versions.
-
-    Accepts the modern ``check_vma`` kwarg and forwards it under whichever
-    name the installed implementation takes (``check_rep`` on 0.4/0.5).
-    Extra kwargs pass through untouched.
-    """
+    """``shard_map`` through its one sanctioned spelling; extra kwargs pass
+    through untouched."""
     if check_vma is not None:
-        kwargs[_SHARD_MAP_CHECK_KW] = check_vma
+        kwargs["check_vma"] = check_vma
     return _shard_map_impl(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )
@@ -243,14 +198,7 @@ _axis_size_impl: Callable = resolve("axis_size")
 
 def axis_size(axis) -> int:
     """Static size of a named mesh axis (or product over an axis tuple),
-    callable from inside a traced region. Newer JAX spells this
-    ``lax.axis_size`` (which takes tuples natively); 0.4.x needs
-    ``jax.core.axis_frame`` per single axis."""
-    if isinstance(axis, (tuple, list)):
-        n = 1
-        for a in axis:
-            n *= int(_axis_size_impl(a))
-        return n
+    callable from inside a traced region."""
     return int(_axis_size_impl(axis))
 
 

@@ -20,6 +20,7 @@ the reference object.
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
 from typing import Any, Optional
 
@@ -49,7 +50,8 @@ class Resources:
         in flight.
     compilation_cache_dir : opt-in path for JAX's persistent compilation
         cache. When set, :func:`enable_compilation_cache` runs with this
-        path — the cache is process-global, so EVERY builder/search entry
+        path (``JAX_COMPILATION_CACHE_DIR`` takes precedence when set) —
+        the cache is process-global, so EVERY builder/search entry
         (all of them jit-compiled programs) transparently reads and writes
         it from then on: a fresh process rebuilding a same-shape index pays
         executable deserialization instead of XLA compilation (the serving
@@ -98,10 +100,10 @@ class Resources:
 
     # -- device properties ---------------------------------------------------
     def device_kind(self) -> str:
-        return getattr(self.device, "device_kind", "cpu")
+        return self.device.device_kind
 
     def is_tpu(self) -> bool:
-        return getattr(self.device, "platform", "cpu") == "tpu"
+        return self.device.platform == "tpu"
 
     def sync(self, *arrays) -> None:
         """Block until the given arrays (or, with no args, all dispatched
@@ -124,17 +126,35 @@ _cache_lock = threading.Lock()
 _cache_dir_enabled: Optional[str] = None
 
 
+def _resolve_cache_dir(path: Optional[str] = None) -> str:
+    """The cache directory :func:`enable_compilation_cache` uses."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if path:
+        return path
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))),
+        ".jax_cache",
+    )
+
+
 def enable_compilation_cache(
-    path: str,
+    path: Optional[str] = None,
     *,
     min_compile_time_secs: float = 0.0,
     min_entry_size_bytes: int = -1,
-) -> None:
-    """Enable JAX's persistent compilation cache at ``path`` (idempotent).
+) -> str:
+    """Enable JAX's persistent compilation cache (idempotent) at
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set, else ``path`` when
+    given, else ``<repo>/.jax_cache`` (a fixed path: the path is part of
+    the cache key, so a directory that moves never hits) — and return
+    the directory.
 
     Every jitted program compiled after this call — index builds, search
-    programs, the shard_map mesh programs — is serialized under ``path``
-    and deserialized by later processes instead of recompiled. The r5
+    programs, the shard_map mesh programs — is serialized there and
+    deserialized by later processes instead of recompiled. The r5
     bench showed compile, not compute, dominating builds (cold 125-250 s
     vs 1.6-15 s warm); this turns that cold start into a disk read.
 
@@ -145,16 +165,15 @@ def enable_compilation_cache(
       helper programs per build whose compiles add up;
     * ``min_entry_size_bytes=-1``: no size floor.
 
-    The enable decision is memoized by JAX at the FIRST compile of the
-    process (``is_cache_used``), so enabling after any jit has run needs a
-    cache reset — compat.compilation_cache_reset does that; in-memory
-    executables are unaffected. Thread-safe; re-enabling with the same
-    path is a no-op, a different path switches the cache over.
+    Thread-safe; re-enabling with the same directory is a no-op, a
+    different one switches the cache over (after other JAX work too: the
+    cache JAX already opened is reset).
     """
     global _cache_dir_enabled
+    path = _resolve_cache_dir(path)
     with _cache_lock:
         if _cache_dir_enabled == path:
-            return
+            return path
         jax.config.update("jax_compilation_cache_dir", path)
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs",
@@ -164,13 +183,13 @@ def enable_compilation_cache(
             "jax_persistent_cache_min_entry_size_bytes",
             int(min_entry_size_bytes),
         )
-        # drop the memoized "cache disabled" decision a pre-enable compile
-        # may have locked in (observed on jax 0.4.37: enabling after
-        # backend init silently writes nothing without this)
+        # JAX opens the cache once per process at its first compile and
+        # keeps that directory: drop it so the next compile opens `path`
         from raft_tpu import compat
 
         compat.compilation_cache_reset()
         _cache_dir_enabled = path
+        return path
 
 
 def compilation_cache_dir() -> Optional[str]:

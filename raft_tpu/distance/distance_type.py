@@ -1,4 +1,4 @@
-"""Distance metric taxonomy — analog of the reference enum
+"""Distance metric classification — analog of the reference enum
 ``raft::distance::DistanceType`` (cpp/include/raft/distance/distance_type.hpp:26-66).
 
 Every enum member of the reference is present; the subset implemented for

@@ -237,7 +237,9 @@ def _resolve_beam_engine(use_pallas, d: int, c: int) -> bool:
         from raft_tpu.spatial.ann import graph_kernel as gk
 
         c_pad = gk.scan_core.round_up(c, gk.scan_core.LANE)
-        return gk.beam_scan_supported(d, c_pad)
+        return gk.scan_core.auto_kernel(
+            gk.beam_scan_supported(d, c_pad), "graph", f"d={d} c={c}"
+        )
     if use_pallas:
         from raft_tpu.spatial.ann import graph_kernel as gk
 

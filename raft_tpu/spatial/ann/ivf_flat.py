@@ -198,9 +198,12 @@ def _resolve_scan_engine(use_pallas, d: int, qcap: int) -> bool:
     if use_pallas is None:
         if jax.default_backend() != "tpu":
             return False
+        from raft_tpu.spatial.ann import scan_core
         from raft_tpu.spatial.ann.flat_kernel import flat_scan_supported
 
-        return flat_scan_supported(d, qcap)
+        return scan_core.auto_kernel(
+            flat_scan_supported(d, qcap), "ivf_flat", f"d={d} qcap={qcap}"
+        )
     if use_pallas:
         from raft_tpu.spatial.ann.flat_kernel import flat_scan_supported
 

@@ -532,9 +532,13 @@ def _resolve_adc_engine(use_pallas, refine_active: bool, pq_dim: int,
     if use_pallas is None:
         if jax.default_backend() != "tpu" or not refine_active:
             return False
+        from raft_tpu.spatial.ann import scan_core
         from raft_tpu.spatial.ann.pq_kernel import pq_adc_supported
 
-        return pq_adc_supported(pq_dim, pq_bits, qcap)
+        return scan_core.auto_kernel(
+            pq_adc_supported(pq_dim, pq_bits, qcap), "ivf_pq",
+            f"pq_dim={pq_dim} pq_bits={pq_bits} qcap={qcap}",
+        )
     if use_pallas:
         from raft_tpu.spatial.ann.pq_kernel import pq_adc_supported
 

@@ -180,9 +180,12 @@ def _resolve_sq_engine(use_pallas, d: int, qcap: int) -> bool:
     if use_pallas is None:
         if jax.default_backend() != "tpu":
             return False
+        from raft_tpu.spatial.ann import scan_core
         from raft_tpu.spatial.ann.sq_kernel import sq_scan_supported
 
-        return sq_scan_supported(d, qcap)
+        return scan_core.auto_kernel(
+            sq_scan_supported(d, qcap), "ivf_sq", f"d={d} qcap={qcap}"
+        )
     if use_pallas:
         from raft_tpu.spatial.ann.sq_kernel import sq_scan_supported
 

@@ -122,21 +122,23 @@ def pq_adc_subchunk_min(luts, codes_t, bounds, *, interpret: bool,
             f"pq_dim {m_dim}"
         )
     k_dim = mk // m_dim
-    kidx = jnp.arange(k_dim, dtype=jnp.uint8)[:, None]         # (K, 1)
+    kidx = jnp.arange(k_dim, dtype=jnp.int32)[:, None]         # (K, 1)
 
     def tile_fn(res, til, bc):
         lut = res[0]                          # (Qp, MK) bf16
         codes = til[0]                        # (M, Lt)  u8
-        kcol = bc[0]                          # (K, 1)   u8
+        kcol = bc[0]                          # (K, 1)   i32
         m = codes.shape[0]
         kd = kcol.shape[0]
         lt = codes.shape[1]
-        # one-hot[m*K + k, l] = (codes[m, l] == k): a u8 compare against
+        # one-hot[m*K + k, l] = (codes[m, l] == k): a compare against
         # the constant (K, 1) index column — the byte-index gather,
         # spelled as an MXU operand (Mosaic on this toolchain has no
         # dynamic-gather lowering; the expansion is VMEM-only, which is
-        # the point)
-        oh = (codes[:, None, :] == kcol[None, :, :])           # (M, K, Lt)
+        # the point). The compare runs in int32: the v5e VPU has no
+        # 8-bit compare.
+        oh = (codes.astype(jnp.int32)[:, None, :]
+              == kcol[None, :, :])                             # (M, K, Lt)
         ohf = oh.reshape(m * kd, lt).astype(jnp.bfloat16)
         return jax.lax.dot_general(
             lut, ohf, (((1,), (0,)), ((), ())),
@@ -161,8 +163,9 @@ def pq_adc_subchunk_min_lax(luts, codes_t, bounds):
     lb, q_pad, mk = luts.shape
     m_dim, l_pad = codes_t.shape[1], codes_t.shape[2]
     k_dim = mk // m_dim
-    kidx = jnp.arange(k_dim, dtype=jnp.uint8)
-    oh = codes_t[:, :, None, :] == kidx[None, None, :, None]   # (LB,M,K,Lp)
+    kidx = jnp.arange(k_dim, dtype=jnp.int32)
+    oh = (codes_t.astype(jnp.int32)[:, :, None, :]
+          == kidx[None, None, :, None])                        # (LB,M,K,Lp)
     ohf = oh.reshape(lb, mk, l_pad).astype(jnp.bfloat16)
     d2 = jax.lax.dot_general(
         luts.astype(jnp.bfloat16), ohf, (((2,), (1,)), ((0,), (0,))),

@@ -59,6 +59,7 @@ callers reach it only through an engine's explicit ``use_pallas`` opt-in
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -68,7 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "BIG", "LANE", "Q_GRANULE", "SUBCHUNK", "VMEM_BUDGET",
-    "l2_gram_tile", "mask_slab_range", "mask_subchunk_min_lax",
+    "auto_kernel", "l2_gram_tile", "mask_slab_range", "mask_subchunk_min_lax",
     "pad_queries", "plan_l_tile", "round_up", "subchunk_min",
     "subchunk_scan", "tile_profile",
 ]
@@ -118,6 +119,25 @@ def tile_profile(qcap: int) -> str:
     profile is a trace-time constant and can never flip at serve
     time."""
     return "latency" if qcap <= _LATENCY_QCAP else "throughput"
+
+
+def auto_kernel(supported: bool, engine: str, config: str) -> bool:
+    """The auto (``use_pallas=None``) engine choice of a grouped search
+    on a TPU backend: the kernel when ``supported``, else the XLA scan —
+    said once per (engine, config) as a warning, never silently."""
+    if not supported:
+        _warn_xla_fallback(engine, config)
+    return supported
+
+
+@functools.lru_cache(maxsize=None)
+def _warn_xla_fallback(engine: str, config: str) -> None:
+    from raft_tpu.core import logger
+
+    logger.warn(
+        "%s: the Pallas kernel does not fit its VMEM plan at %s; this "
+        "search runs the XLA scan on the TPU", engine, config,
+    )
 
 
 def plan_l_tile(step_bytes: Callable[[int, int], int], q_pad: int,
@@ -175,9 +195,12 @@ def mask_slab_range(d2, col0, lo, hi, big: float = BIG):
 
 def subchunk_min(d2, sub: int = SUBCHUNK):
     """Min-reduce one (Q, Lt) tile over ``sub``-row granules — the only
-    thing a scan kernel writes out: (Q, Lt/sub) minima."""
+    thing a scan kernel writes out — as (Lt/sub, Q) minima: the tile is
+    transposed first so the granule reduce runs over sublanes and the
+    query axis rides the lanes (the fused_knn chunk-min layout; Mosaic
+    refuses a lane-axis ``(Q, Lt) -> (Q, Lt/sub, sub)`` reshape)."""
     q_pad, lt = d2.shape
-    return jnp.min(d2.reshape(q_pad, lt // sub, sub), axis=2)
+    return jnp.min(d2.T.reshape(lt // sub, sub, q_pad), axis=1)
 
 
 def mask_subchunk_min_lax(d2, bounds, sub: int = SUBCHUNK,
@@ -229,6 +252,12 @@ def subchunk_scan(tile_fn, bounds, resident: Sequence, tiled: Sequence,
       the sub-chunk min, and nothing but the (LB, Q, Lpad/sub) minima
       ever reaches HBM.
 
+    The kernel writes each step's minima as an (Lpad/sub, Q) block
+    (:func:`subchunk_min`): its last dim is the whole query axis, which
+    Mosaic accepts at every ``l_tile`` the planner returns; the driver
+    swaps the two axes back outside the kernel (an XLA pass over the
+    minima only, 1/sub of the tile).
+
     The q_pad is taken from ``resident[0].shape[1]`` (every engine's
     first resident operand carries the query axis)."""
     lb = tiled[0].shape[0]
@@ -278,12 +307,13 @@ def subchunk_scan(tile_fn, bounds, resident: Sequence, tiled: Sequence,
             num_scalar_prefetch=1,
             grid=(lb, l_pad // l_tile),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, q_pad, l_tile // sub),
-                                   lambda b, t, bnd: (b, 0, t)),
+            out_specs=pl.BlockSpec((1, l_tile // sub, q_pad),
+                                   lambda b, t, bnd: (b, t, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct(
-            (lb, q_pad, l_pad // sub), jnp.float32
+            (lb, l_pad // sub, q_pad), jnp.float32
         ),
         interpret=interpret,
+        name=name,
     )(bounds.astype(jnp.int32), *resident, *tiled, *broadcast)
-    return out
+    return jnp.swapaxes(out, 1, 2)

@@ -43,12 +43,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from raft_tpu.distance.distance_type import DistanceType
 
-__all__ = ["fused_l2_knn", "fused_knn_supported", "fused_grid_ok"]
+__all__ = ["fused_l2_knn", "fused_knn_supported"]
 
 _CHUNK = 128  # lane width: one chunk-min per vreg row per reduce
-
-# Per-program grid-step budget for one Pallas call — see _max_grid_steps()
-_MAX_GRID_STEPS_DEFAULT = 6000
 
 
 def _cdiv(a, b):
@@ -59,54 +56,65 @@ def _round_up(a, b):
     return _cdiv(a, b) * b
 
 
-def _chunkmin_kernel(y_ref, qt_ref, ynorm_ref, o_ref, *, nc):
+def _chunkmin_kernel(y_ref, qt_ref, o_ref, *, nc, n_valid, compute_dtype):
     """One (bn, bm) transposed score tile -> (bn/128, bm) chunk minima.
 
     y_ref (bn, d) index rows; qt_ref (d, bm) feature-major queries so the
-    gram is a natural MXU contraction; ynorm_ref (bn, 1); o_ref (nc, bm).
+    gram is a natural MXU contraction; o_ref (nc, bm).
     The tile is computed transposed — scores (bn, bm) — so the 128-column
     chunk reduction runs over *sublanes* (cheap VPU shape) and the output
     keeps queries on the 128-aligned lane axis.
+    The index tile is cast to ``compute_dtype`` here, in VMEM: a cast
+    in XLA writes a converted copy of the whole index to HBM (12.5M x 96
+    bf16 -> f32 is 6.4 GB with lane padding). The row norms are computed
+    here from the tile too: an (n, 1) f32 norms operand is laid out 128
+    lanes wide in HBM (another 6.4 GB at 12.5M rows; a v5e compile of
+    the kernel that took one showed 9.6 GB of temporaries).
+    Rows at or past ``n_valid`` (the padding) score BIG, so they never
+    win a chunk.
     Scores drop the per-query ||x||^2 term — constant within a query, so
     chunk *ranking* (all phase 1 is for) is unchanged.
     """
-    g = jnp.dot(
-        y_ref[:], qt_ref[:], preferred_element_type=jnp.float32
-    )  # (bn, bm) MXU
-    scores = ynorm_ref[:] - 2.0 * g
-    bn, bm = scores.shape
+    y = y_ref[:]
+    g = jnp.dot(y.astype(compute_dtype), qt_ref[:],
+                preferred_element_type=jnp.float32)
+    yf = y.astype(jnp.float32)
+    bn, bm = g.shape
+    row = pl.program_id(1) * bn + lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
+    yn = jnp.where(row < n_valid, jnp.sum(yf * yf, axis=1, keepdims=True),
+                   jnp.float32(1e30))
+    scores = yn - 2.0 * g  # (bn, bm) MXU
     o_ref[:, :] = jnp.min(scores.reshape(nc, _CHUNK, bm), axis=1)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bn", "compute_dtype", "interpret"),
+    static_argnames=("n_valid", "bm", "bn", "compute_dtype", "interpret"),
 )
-def _chunk_mins(
-    q, yp, ynorm_padded, *, bm, bn, compute_dtype, interpret
-):
-    """Phase 1 driver: (m, d) x (npad, d) -> (m, npad/128) chunk minima."""
+def _chunk_mins(q, yp, *, n_valid, bm, bn, compute_dtype, interpret):
+    """Phase 1 driver: (m, d) x (npad, d) -> (m, npad/128) chunk minima;
+    rows at or past ``n_valid`` are padding."""
     m, d = q.shape
     npad = yp.shape[0]
     mp = _round_up(m, bm)
     nc_tile = bn // _CHUNK
 
     qtp = jnp.pad(q, ((0, mp - m), (0, 0))).T.astype(compute_dtype)
-    ypc = yp.astype(compute_dtype)
 
-    kernel = functools.partial(_chunkmin_kernel, nc=nc_tile)
+    kernel = functools.partial(_chunkmin_kernel, nc=nc_tile, n_valid=n_valid,
+                               compute_dtype=compute_dtype)
     out = pl.pallas_call(
         kernel,
         grid=(mp // bm, npad // bn),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
             pl.BlockSpec((d, bm), lambda i, j: (0, i)),
-            pl.BlockSpec((bn, 1), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((nc_tile, bm), lambda i, j: (j, i)),
         out_shape=jax.ShapeDtypeStruct((npad // _CHUNK, mp), jnp.float32),
         interpret=interpret,
-    )(ypc, qtp, ynorm_padded)
+        name="fused_knn_chunk_mins",
+    )(yp, qtp)
     return out[:, :m].T
 
 
@@ -209,7 +217,7 @@ def _rescore_scores(q, cids, yp, *, c, interpret):
     jax.jit,
     static_argnames=("k", "metric", "bm", "bn", "bq2", "extra_chunks",
                      "compute_dtype", "interpret", "gather_rows",
-                     "grid_limit"),
+                     "rescore_rows"),
 )
 def _fused_l2_knn_impl(
     queries,
@@ -225,7 +233,7 @@ def _fused_l2_knn_impl(
     interpret: bool,
     gather_rows=None,
     index_norms=None,
-    grid_limit: int = _MAX_GRID_STEPS_DEFAULT,
+    rescore_rows: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     m, d = queries.shape
     n = index.shape[0]
@@ -244,18 +252,9 @@ def _fused_l2_knn_impl(
     # multi-GB index is not reliably elided and would copy it (fatal for
     # the HBM-resident big-index regime)
     yp = y if npad == n else jnp.pad(y, ((0, npad - n), (0, 0)))
-    # caller-precomputed norms skip a full index read per search — the
-    # analog of the reference storing norms with the index
-    # (knn_brute_force_faiss.cuh:318-330 norms argument)
-    yn = (
-        jnp.asarray(index_norms, jnp.float32)
-        if index_norms is not None
-        else jnp.einsum("nd,nd->n", y, y, preferred_element_type=jnp.float32)
-    )
-    ynp = yn if npad == n else jnp.pad(yn, (0, npad - n), constant_values=BIG)
 
     cmins = _chunk_mins(
-        q, yp, ynp[:, None], bm=bm, bn=bn,
+        q, yp, n_valid=n, bm=bm, bn=bn,
         compute_dtype=compute_dtype, interpret=interpret,
     )  # (m, nC)
 
@@ -273,17 +272,20 @@ def _fused_l2_knn_impl(
     # index's native layout (no relayout copy, ~10x the XLA gather; see
     # _rescore_dma_kernel). Requires the padded candidate count to be a
     # multiple of 8 (1-D output tiling); query batches beyond the
-    # per-call grid budget tile into <= grid_limit-row kernel calls so
+    # per-call SMEM bound tile into <= rescore_rows-row kernel calls so
     # the throughput case (big m) keeps the DMA path. `gather_rows`
     # explicitly pins the XLA fallback variants (exercised by tests).
     cpad = _round_up(c, 8)
     mp8 = _round_up(m, _QBLK)
-    # per-call tile bound: the compile-helper grid budget AND the
-    # scalar-prefetch SMEM footprint — the prefetched (rows, cpad)
-    # chunk-id operand costs round_up(cpad, 128)*4 bytes/row of the
-    # ~1 MiB SMEM (measured: 2000 rows compile at cpad=24, 2048 do
-    # not); budget 3/4 MiB to leave slack for Mosaic's own SMEM
+    # per-call tile bound: the scalar-prefetch SMEM footprint — the
+    # prefetched (rows, cpad) chunk-id operand costs
+    # round_up(cpad, 128)*4 bytes/row of the ~1 MiB SMEM (measured:
+    # 2000 rows compile at cpad=24, 2048 do not); budget 3/4 MiB to
+    # leave slack for Mosaic's own SMEM. `rescore_rows` lowers it
+    # (tests force the tiled path at small m).
     smem_rows = (768 * 1024) // (_round_up(cpad, 128) * 4)
+    if rescore_rows is not None:
+        smem_rows = min(smem_rows, rescore_rows)
     use_dma = (
         gather_rows is None
         and cpad <= nC
@@ -291,20 +293,18 @@ def _fused_l2_knn_impl(
         # feature dims take the XLA gather fallback (small-d regime,
         # where the chunk-major gather is cheap anyway)
         and d % _CHUNK == 0
-        # neither budget can hold even one _QBLK-row tile (very large
-        # cpad, or a caller-pinned tiny grid budget): take the XLA
-        # gather path rather than clamping the tile past the budget,
-        # which recreates the scalar-prefetch compile failure the
-        # tiling exists to avoid
+        # the SMEM bound cannot hold even one _QBLK-row tile (very
+        # large cpad): take the XLA gather path rather than clamping the
+        # tile past the bound, which recreates the scalar-prefetch
+        # compile failure the tiling exists to avoid
         and smem_rows >= _QBLK
-        and grid_limit >= _QBLK
     )
     if use_dma:
         _, cids = lax.top_k(-cmins, cpad)               # (m, cpad)
         qpad = q if mp8 == m else jnp.pad(q, ((0, mp8 - m), (0, 0)))
         cpds = cids if mp8 == m else jnp.pad(cids, ((0, mp8 - m), (0, 0)))
         cpds = cpds.astype(jnp.int32)
-        blk = min(grid_limit, smem_rows) // _QBLK * _QBLK
+        blk = smem_rows // _QBLK * _QBLK
         if mp8 <= blk:
             scores = _rescore_scores(
                 qpad, cpds, yp, c=cpad, interpret=interpret
@@ -358,6 +358,15 @@ def _fused_l2_knn_impl(
     )
     if not big_index:
         ychunks = yp.reshape(nC, _CHUNK * d)
+    # caller-precomputed norms skip a full index read here — the analog
+    # of the reference storing norms with the index
+    # (knn_brute_force_faiss.cuh:318-330 norms argument)
+    yn = (
+        jnp.asarray(index_norms, jnp.float32)
+        if index_norms is not None
+        else jnp.einsum("nd,nd->n", y, y, preferred_element_type=jnp.float32)
+    )
+    ynp = yn if npad == n else jnp.pad(yn, (0, npad - n), constant_values=BIG)
     ynchunks = ynp.reshape(nC, _CHUNK)
 
     qn = jnp.sum(q * q, axis=-1)
@@ -413,56 +422,6 @@ _L2_FAMILY = (
     DistanceType.L2Unexpanded,
 )
 
-# The default grid budget was measured against THIS environment's compile
-# helper (6144 compiles, 7812 does not); because such limits can move
-# across toolchain updates it is overridable via RAFT_TPU_MAX_GRID_STEPS
-# (read at call time — set it before the first call for a given shape, as
-# compiled programs cache their routing), and `probe_grid_steps(n)` lets a
-# deployment verify a candidate budget once (trivial-kernel AOT compile)
-# before raising it.
-def _max_grid_steps() -> int:
-    import os
-
-    env = os.environ.get("RAFT_TPU_MAX_GRID_STEPS")
-    if not env:
-        return _MAX_GRID_STEPS_DEFAULT
-    try:
-        val = int(env)
-    except ValueError:
-        raise ValueError(
-            f"RAFT_TPU_MAX_GRID_STEPS must be a positive integer, "
-            f"got {env!r}"
-        ) from None
-    if val <= 0:
-        raise ValueError(
-            f"RAFT_TPU_MAX_GRID_STEPS must be positive, got {val}"
-        )
-    return val
-
-
-def probe_grid_steps(steps: int) -> bool:
-    """Whether a trivial ``steps``-step Pallas grid compiles on the current
-    backend — a one-time probe deployments can run before overriding
-    RAFT_TPU_MAX_GRID_STEPS (the compile-helper grid budget is an
-    environment property, not an architectural constant)."""
-
-    def _k(x_ref, o_ref):
-        o_ref[:, :] = x_ref[:, :]
-
-    try:
-        fn = pl.pallas_call(
-            _k,
-            grid=(steps,),
-            in_specs=[pl.BlockSpec((8, 128), lambda i: (0, 0))],
-            out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-        )
-        jax.jit(fn).lower(jnp.zeros((8, 128), jnp.float32)).compile()
-        return True
-    except Exception:
-        return False
-
-
 def _plan_blocks(m: int, n: int, d: int, bm: int = 1024, bn: int = 2048):
     """Resolve phase-1 tile sizes: VMEM-bounded for wide d, 128-aligned."""
     bn = min(bn, _round_up(n, _CHUNK))
@@ -474,19 +433,6 @@ def _plan_blocks(m: int, n: int, d: int, bm: int = 1024, bn: int = 2048):
         if bm > 256:
             bm //= 2
     return bm, bn
-
-
-def _grid_steps(m: int, n: int, bm: int, bn: int) -> int:
-    return _cdiv(m, bm) * _cdiv(_round_up(n, bn), bn)
-
-
-def fused_grid_ok(m: int, n: int, d: int, bm: int = 1024,
-                  bn: int = 2048) -> bool:
-    """Whether one fused call at this shape stays under the compile
-    helper's per-program grid-step limit (callers above the limit should
-    partition the index or take the scan path)."""
-    pbm, pbn = _plan_blocks(m, n, d, bm, bn)
-    return _grid_steps(m, n, pbm, pbn) <= _max_grid_steps()
 
 
 def fused_knn_supported(
@@ -536,10 +482,11 @@ def fused_l2_knn(
     reference, knn_brute_force_faiss.cuh:240-254).
 
     ``index_norms``: optional precomputed ``sum(index**2, axis=1)`` (f32,
-    shape (n,)). Searching many query batches against a fixed index
-    otherwise re-reads the whole index once per call for norms — the
-    reference stores norms with the index for the same reason
-    (knn_brute_force_faiss.cuh:318-330).
+    shape (n,)), read by the XLA gather rescore (feature dims that are
+    not a multiple of 128, or ``gather_rows``), which otherwise re-reads
+    the whole index once per call for norms — the reference stores norms
+    with the index for the same reason (knn_brute_force_faiss.cuh:318-330).
+    Phase 1 computes norms from the tiles it already reads.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -552,20 +499,6 @@ def fused_l2_knn(
             f"fused kNN unsupported for metric={metric} m={m} n={n} d={d} k={k}"
         )
     bm, bn = _plan_blocks(m, n, d, bm, bn)
-    # the TPU compile helper rejects Pallas programs beyond ~6k total grid
-    # steps (measured: 6144 compiles, 7812 does not); beyond that the index
-    # must be partitioned — brute_force_knn(list_of_partitions) runs this
-    # kernel per partition and knn_merge_parts the results (its auto
-    # dispatch checks fused_grid_ok and falls back to the scan path).
-    steps = _grid_steps(m, n, bm, bn)
-    limit = _max_grid_steps()
-    if steps > limit:
-        raise ValueError(
-            f"fused kNN grid too large ({steps} steps > {limit}): "
-            f"split the index into partitions of <= "
-            f"{limit // _cdiv(m, bm) * bn} rows "
-            f"and use brute_force_knn(partitions, ...)"
-        )
     if index_norms is not None:
         index_norms = jnp.asarray(index_norms)
         errors_ok = index_norms.ndim == 1 and index_norms.shape[0] == n
@@ -578,7 +511,7 @@ def fused_l2_knn(
         bm=bm, bn=bn, bq2=bq2, extra_chunks=extra_chunks,
         compute_dtype=jnp.dtype(compute_dtype),
         interpret=interpret, gather_rows=gather_rows,
-        index_norms=index_norms, grid_limit=limit,
+        index_norms=index_norms,
     )
     if init is not None:
         from raft_tpu.spatial.selection import merge_topk

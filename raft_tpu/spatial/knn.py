@@ -35,7 +35,7 @@ from raft_tpu.distance.pairwise import (
 from raft_tpu.distance.distance_type import EXPANDED_METRICS
 from raft_tpu.spatial.selection import select_k, merge_topk, chunk_min_select_k
 from raft_tpu.spatial.fused_knn import (
-    fused_grid_ok, fused_l2_knn, fused_knn_supported,
+    fused_l2_knn, fused_knn_supported,
 )
 
 __all__ = [
@@ -173,9 +173,9 @@ def brute_force_knn(
     scan path at SIFT-1M shape); other metrics/shapes take the streaming
     scan path. ``compute_dtype``/``extra_chunks`` tune the fused path
     (fused_l2_knn docs); ``compute_dtype=bfloat16`` with bf16 partitions
-    is the HBM-resident big-index mode — partitioning also keeps each
-    Pallas grid under the compiler's step limit, so a ~14 GB index runs
-    as 3-4 bf16 partitions (the 10M x 768 BASELINE regime).
+    is the HBM-resident big-index mode; a ~14 GB index runs as 3-4 bf16
+    partitions so that each call's padded copy fits beside it (the
+    10M x 768 BASELINE regime).
 
     ``index_norms``: optional per-partition precomputed squared row norms
     (list matching ``index``); repeated searches against a fixed index
@@ -217,7 +217,6 @@ def brute_force_knn(
             use_fused is None
             and fused_ok
             and n >= 65536
-            and fused_grid_ok(m, n, d)  # else fall back to the scan path
             and jax.default_backend() == "tpu"
         ):
             if not fused_ok:

@@ -159,10 +159,11 @@ def test_fused_knn_warm_start(rng_np):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
-def test_fused_knn_rescore_tiles_beyond_grid_limit(rng_np):
-    """Query batches whose padded row count exceeds the per-call grid
-    budget must keep the DMA rescore path by tiling into <= grid_limit
-    kernel calls (not silently fall back to the XLA gather)."""
+def test_fused_knn_rescore_tiles_beyond_smem_rows(rng_np):
+    """Query batches whose padded row count exceeds the per-call SMEM
+    row bound must keep the DMA rescore path by tiling into
+    <= rescore_rows kernel calls (not silently fall back to the XLA
+    gather)."""
     from raft_tpu.spatial.fused_knn import _fused_l2_knn_impl
 
     q = rng_np.standard_normal((40, 128)).astype(np.float32)
@@ -170,7 +171,7 @@ def test_fused_knn_rescore_tiles_beyond_grid_limit(rng_np):
     dt, it = _fused_l2_knn_impl(
         q, y, 5, DistanceType.L2SqrtExpanded, bm=1024, bn=2048, bq2=40,
         extra_chunks=8, compute_dtype=jnp.dtype(jnp.float32),
-        interpret=True, grid_limit=16,    # forces ceil(40/16)=3 tiles
+        interpret=True, rescore_rows=16,  # forces ceil(40/16)=3 tiles
     )
     dref, iref = fused_l2_knn(q, y, 5)
     np.testing.assert_array_equal(np.asarray(it), np.asarray(iref))

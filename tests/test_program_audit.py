@@ -274,6 +274,8 @@ def test_dtype_flow_flags_64bit():
     # record instead of enabling it (the x64 harness owns that process)
     import dataclasses as dc
 
+    from jax.extend.core import ClosedJaxpr
+
     def f(x):
         return x + 1
 
@@ -286,7 +288,7 @@ def test_dtype_flow_flags_64bit():
 
     fake_eqn = real.eqn.replace(outvars=[FakeVar()])
     fake_jaxpr = rec.jaxpr.jaxpr.replace(eqns=[fake_eqn])
-    rec64 = dc.replace(rec, jaxpr=jax.core.ClosedJaxpr(fake_jaxpr, []))
+    rec64 = dc.replace(rec, jaxpr=ClosedJaxpr(fake_jaxpr, []))
     contract, findings = dtype_flow(rec64)
     assert rules_of(findings) == ["dtype-flow"]
     assert "float64" in findings[0].message

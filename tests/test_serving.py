@@ -158,7 +158,8 @@ class TestExpandProbeSet:
 
 # ------------------------------------------- persistent compilation cache
 class TestCompilationCache:
-    def test_resources_arg_enables_and_populates(self, tmp_path):
+    def test_resources_arg_enables_and_populates(self, tmp_path,
+                                                 monkeypatch):
         from raft_tpu import compat
         from raft_tpu.core import (
             Resources,
@@ -182,6 +183,10 @@ class TestCompilationCache:
         }
         prior_enabled = resources_mod._cache_dir_enabled
         try:
+            # the environment places the cache when it names one
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache + "_env")
+            assert enable_compilation_cache(cache) == cache + "_env"
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
             Resources(compilation_cache_dir=cache)
             assert compilation_cache_dir() == cache
 
