@@ -3,18 +3,28 @@
 Reference: cpp/include/raft/core/nvtx.hpp:48-91 and
 common/detail/nvtx.hpp:23-206 (RAII ``nvtx::range``, push_range/pop_range,
 per-domain colored ranges, compiled out when NVTX disabled). The TPU analog
-uses ``jax.profiler``: ``TraceAnnotation`` shows up on the XLA trace viewer
-timeline and ``jax.named_scope`` tags HLO ops so ranges survive into compiled
-profiles.
+uses ``jax.profiler``: a range is a ``TraceAnnotation`` on the profiler's
+host timeline, the clock the device planes of the same capture share, so a
+range can be set against the device's operations with no clock mapping.
+Keyword stats (``annotate("serving.stage", batch_id=7)``) ride on the
+event, which is how the ranges of one batch are tied together.
+
+Host ranges do NOT enter ``jax.named_scope``: a scope entered on a host
+thread would stamp its label into the metadata of any program compiled
+inside it, so a compile during a capture would differ from one outside it
+(and miss the persistent compile cache). Device code names its parts with
+``jax.named_scope`` directly, at trace time (the grouped IVF program's
+``ivf.*`` and the brute-force program's ``knn.*`` scopes;
+docs/observability.md "Spans and scopes").
 
 Like the reference's ``NVTX_ENABLED`` compile-out, ranges honor a GLOBAL
 enable flag: when profiling is off (the default — set ``RAFT_TPU_PROFILE=1``
-to force it on), :func:`annotate` and :func:`push_range` are TRUE no-ops —
-no ``TraceAnnotation``, no ``ExitStack``, no stack append — so the hot
-serving path pays one module-attribute load per range
-(tests/test_obs.py pins the no-allocation claim). :func:`start_trace`
-flips the flag on for the duration of a capture (and :func:`stop_trace`
-restores it), so an SLO-triggered capture
+to force it on), :func:`annotate` returns one shared no-op context and
+:func:`push_range` returns at once — no ``TraceAnnotation``, no
+``ExitStack``, no stack append — so the hot serving path pays one
+list load per range (tests/test_obs.py pins the no-allocation claim).
+:func:`start_trace` flips the flag on for the duration of a capture (and
+:func:`stop_trace` restores it), so an SLO-triggered capture
 (:class:`raft_tpu.obs.ProfileTrigger`) sees every range without anyone
 paying for them between captures.
 """
@@ -23,7 +33,7 @@ from __future__ import annotations
 
 import contextlib
 import os
-from typing import Iterator, List
+from typing import Any, ContextManager, List
 
 import jax
 
@@ -40,6 +50,8 @@ _ENABLED: List[bool] = [_ENV_DEFAULT]
 _stack: List[contextlib.ExitStack] = []
 # profiling state before start_trace flipped it, restored by stop_trace
 _pre_trace: List[bool] = []
+# what annotate() returns while profiling is off: one shared no-op
+_OFF: ContextManager[None] = contextlib.nullcontext()
 
 
 def profiling_enabled() -> bool:
@@ -56,20 +68,20 @@ def set_profiling(on: bool) -> bool:
     return prev
 
 
-@contextlib.contextmanager
-def annotate(name: str, *args) -> Iterator[None]:
-    """RAII-style range, usable as a decorator or context manager.
+def annotate(name: str, *args: Any, **stats: Any) -> ContextManager[Any]:
+    """RAII-style range: a context manager.
 
     ``args`` are %-formatted into ``name`` like the reference's printf-style
-    range names (nvtx.hpp:54 ``range(const char* format, Args... args)``).
-    A no-op (no profiler objects constructed) while profiling is off.
+    range names (nvtx.hpp:54 ``range(const char* format, Args... args)``);
+    ``stats`` are attached to the event as trace stats (numbers or
+    strings). Entering the range yields the ``TraceAnnotation``, whose
+    ``set_metadata(**stats)`` adds stats known only inside the range, or
+    None while profiling is off — then nothing is constructed.
     """
     if not _ENABLED[0]:
-        yield
-        return
+        return _OFF
     label = name % args if args else name
-    with jax.profiler.TraceAnnotation(label), jax.named_scope(label):
-        yield
+    return jax.profiler.TraceAnnotation(label, **stats)
 
 
 def push_range(name: str, *args) -> None:
@@ -97,14 +109,18 @@ def pop_range() -> None:
         )
 
 
-def start_trace(log_dir: str) -> None:
+def start_trace(log_dir: str, options: Any = None) -> None:
     """Start an XLA profiler trace capture (output viewable in
-    TensorBoard) and enable range emission for its duration. The
-    profiler starts FIRST: if it refuses (a capture is already
-    running), the range gate and its restore stack are untouched — a
-    failed start must not leave every later range permanently paid
-    for."""
-    jax.profiler.start_trace(log_dir)
+    TensorBoard) and enable range emission for its duration.
+    ``options`` is a ``jax.profiler.ProfileOptions`` (the profiler's
+    defaults when None). The profiler starts FIRST: if it refuses (a
+    capture is already running), the range gate and its restore stack
+    are untouched — a failed start must not leave every later range
+    permanently paid for."""
+    if options is None:
+        jax.profiler.start_trace(log_dir)
+    else:
+        jax.profiler.start_trace(log_dir, profiler_options=options)
     _pre_trace.append(set_profiling(True))
 
 

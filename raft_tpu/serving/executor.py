@@ -58,6 +58,7 @@ import numpy as np
 
 from raft_tpu import compat, errors
 from raft_tpu.analysis.threads import runtime as lockcheck
+from raft_tpu.core.annotate import annotate
 from raft_tpu.core.interruptible import Interruptible
 from raft_tpu.obs import crash as obs_crash
 from raft_tpu.obs import metrics as obs_metrics
@@ -72,18 +73,31 @@ from raft_tpu.serving.batching import (
 )
 from raft_tpu.serving.result_cache import ResultCache, exact_signatures
 
-__all__ = ["ServingExecutor", "ExecutorStats", "STAGES"]
+__all__ = ["ServingExecutor", "ExecutorStats", "STAGES", "SPANS"]
 
 # the serving pipeline's named stages, in hop order — each is a
 # ``serving_stage_ms{executor,stage,bucket}`` histogram recorded from
 # timestamps the executor already takes (docs/observability.md "Stage
 # timing"): queue_wait (submit → packed), batch_build (pack + pad),
+# window_wait (packed → staging starts: the wait for an in-flight slot),
 # staging (host→device put), dispatch_ready (dispatch → drain-loop
 # readiness — the polling gives it for free, no block_until_ready),
 # demux (host conversion + per-request slicing), e2e (submit → future
 # resolved; the SLO-trigger input)
-STAGES = ("queue_wait", "batch_build", "staging", "dispatch_ready",
-          "demux", "e2e")
+STAGES = ("queue_wait", "batch_build", "window_wait", "staging",
+          "dispatch_ready", "demux", "e2e")
+
+# the executor's host spans (core.annotate ranges, so they cost one gate
+# check unless a capture or RAFT_TPU_PROFILE turns them on), each
+# carrying the ``batch_id`` trace stat that ties one batch's spans
+# together (docs/observability.md "Spans and scopes")
+SPAN_PACK = "serving.pack"                # pack + pad (+ bucket, n_requests)
+SPAN_WINDOW_WAIT = "serving.window_wait"  # wait for an in-flight slot
+SPAN_STAGE = "serving.stage"              # the stage (host→device) call
+SPAN_DISPATCH = "serving.dispatch"        # the dispatch call
+SPAN_DEMUX = "serving.demux"              # host conversion, slicing, futures
+SPANS = (SPAN_PACK, SPAN_WINDOW_WAIT, SPAN_STAGE, SPAN_DISPATCH,
+         SPAN_DEMUX)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -674,9 +688,13 @@ class ServingExecutor:
                     break
                 rows = sum(r.n_rows for r in self._pending)
                 t_pack0 = self._clock()
-                batch, self._pending = pack_requests(
-                    self._pending, self.buckets, self.dim
-                )
+                with annotate(SPAN_PACK, batch_id=self._batch_seq) as sp:
+                    batch, self._pending = pack_requests(
+                        self._pending, self.buckets, self.dim
+                    )
+                    if sp is not None and batch is not None:
+                        sp.set_metadata(bucket=batch.bucket,
+                                        n_requests=batch.n_requests)
                 if batch is None:      # unreachable via submit; be safe
                     continue
                 batch.batch_id = self._batch_seq
@@ -698,21 +716,23 @@ class ServingExecutor:
                         batch_id=batch.batch_id, bucket=batch.bucket,
                         start=start,
                     )
-            self._dispatch_batch(batch, runtime, full, epoch)
+            self._dispatch_batch(batch, runtime, full, epoch, t_packed=now)
         with self._done:
             self._batcher_exited = True
             self._done.notify_all()
 
     def _dispatch_batch(self, batch: MicroBatch,
                         runtime: Dict[str, Any], full: bool,
-                        epoch: int = 0) -> None:
+                        epoch: int, t_packed: float) -> None:
+        bid = batch.batch_id
         # window check OUTSIDE the lock: the batcher blocks here (not
         # the submitters) when max_in_flight programs are queued
-        while True:
-            with self._done:
-                if len(self._inflight) < self.max_in_flight:
-                    break
-                self._done.wait(0.05)
+        with annotate(SPAN_WINDOW_WAIT, batch_id=bid):
+            while True:
+                with self._done:
+                    if len(self._inflight) < self.max_in_flight:
+                        break
+                    self._done.wait(0.05)
         ticket = None
         try:
             if self.admission is not None:
@@ -727,10 +747,16 @@ class ServingExecutor:
             # the double buffer (donate-friendly: hedges re-stage from
             # batch.queries, never reuse this device buffer)
             t_s0 = self._clock()
-            staged = self._stage(batch.queries)
+            with annotate(SPAN_STAGE, batch_id=bid):
+                staged = self._stage(batch.queries)
             t0 = self._clock()
             lockcheck.note_dispatch("ServingExecutor._dispatch")
-            out = self._dispatch(staged, **runtime)
+            with annotate(SPAN_DISPATCH, batch_id=bid):
+                out = self._dispatch(staged, **runtime)
+            # packed → staging start: the in-flight window's wait (plus
+            # the admission ticket and runtime sampling, microseconds)
+            self._hist("window_wait", batch.bucket).observe(
+                (t_s0 - t_packed) * 1e3)
             # staging is the host-side cost of the device_put call —
             # the transfer itself overlaps compute (that's the point);
             # a blocking stage override shows up here
@@ -881,63 +907,64 @@ class ServingExecutor:
                 and not hasattr(winner, "shape"):
             winner = winner.value
         t_demux0 = self._clock()
-        try:
-            # the ONE intentional host sync of the serving path: the
-            # winner is already ready, this is the demux conversion
-            host = compat.tree_map(np.asarray, winner)  # jaxlint: disable=sync-in-hot-path
-        except Exception as exc:   # noqa: BLE001
-            self._fail_batch(fl.batch, exc)
-            return
-        # mnmg coverage, read off the ALREADY-converted host result (a
-        # PartialSearchResult-shaped pytree carries .coverage) — the
-        # degraded-serving gauge, no extra sync
-        cov = getattr(host, "coverage", None)
-        if cov is not None:
+        with annotate(SPAN_DEMUX, batch_id=fl.batch.batch_id):
             try:
-                cov_min = float(np.min(cov))
-            except (TypeError, ValueError):
-                cov_min = None
-            if cov_min is not None:
-                if self._g_coverage is None:
-                    self._g_coverage = self._registry.gauge(
-                        "serving_coverage_min", executor=self.name)
-                self._g_coverage.set(cov_min)
-        # retire the batch's coalescing leaders FIRST: once released,
-        # no new follower can attach, so this demux resolves exactly
-        # the snapshot — including followers of a leader whose own
-        # caller cancelled (their rows are right here in the batch)
-        subs = self._release_followers(fl.batch)
-        delivered = 0
-        n_followers = 0
-        for req, start in fl.batch.entries:
-            followers = subs.get(req.req_id, ())
-            if req.future.done() and not followers:
-                continue              # caller cancelled while queued
-            rows = slice(start, start + req.n_rows)
-            result = compat.tree_map(
-                lambda a, rows=rows: a[rows] if (
-                    isinstance(a, np.ndarray) and a.ndim >= 1
-                    and a.shape[0] == bucket
-                ) else a,
-                host,
-            )
-            if not req.future.done():
+                # the ONE intentional host sync of the serving path: the
+                # winner is already ready, this is the demux conversion
+                host = compat.tree_map(np.asarray, winner)  # jaxlint: disable=sync-in-hot-path
+            except Exception as exc:   # noqa: BLE001
+                self._fail_batch(fl.batch, exc)
+                return
+            # mnmg coverage, read off the ALREADY-converted host result (a
+            # PartialSearchResult-shaped pytree carries .coverage) — the
+            # degraded-serving gauge, no extra sync
+            cov = getattr(host, "coverage", None)
+            if cov is not None:
                 try:
-                    req.future.set_result(result)
-                    delivered += 1
-                except InvalidStateError:
-                    pass              # cancel raced the done() check
-            for f in followers:
-                try:
-                    f.set_result(result)
-                    n_followers += 1
-                except InvalidStateError:
-                    pass              # the follower's caller cancelled
-            if self._rcache is not None:
-                # fill AFTER resolving the callers (cache writes are
-                # off the latency path), stamped with the DISPATCH
-                # epoch, re-using the submit-time signatures
-                self._cache_fill(req, result, fl.epoch)
+                    cov_min = float(np.min(cov))
+                except (TypeError, ValueError):
+                    cov_min = None
+                if cov_min is not None:
+                    if self._g_coverage is None:
+                        self._g_coverage = self._registry.gauge(
+                            "serving_coverage_min", executor=self.name)
+                    self._g_coverage.set(cov_min)
+            # retire the batch's coalescing leaders FIRST: once released,
+            # no new follower can attach, so this demux resolves exactly
+            # the snapshot — including followers of a leader whose own
+            # caller cancelled (their rows are right here in the batch)
+            subs = self._release_followers(fl.batch)
+            delivered = 0
+            n_followers = 0
+            for req, start in fl.batch.entries:
+                followers = subs.get(req.req_id, ())
+                if req.future.done() and not followers:
+                    continue              # caller cancelled while queued
+                rows = slice(start, start + req.n_rows)
+                result = compat.tree_map(
+                    lambda a, rows=rows: a[rows] if (
+                        isinstance(a, np.ndarray) and a.ndim >= 1
+                        and a.shape[0] == bucket
+                    ) else a,
+                    host,
+                )
+                if not req.future.done():
+                    try:
+                        req.future.set_result(result)
+                        delivered += 1
+                    except InvalidStateError:
+                        pass              # cancel raced the done() check
+                for f in followers:
+                    try:
+                        f.set_result(result)
+                        n_followers += 1
+                    except InvalidStateError:
+                        pass              # the follower's caller cancelled
+                if self._rcache is not None:
+                    # fill AFTER resolving the callers (cache writes are
+                    # off the latency path), stamped with the DISPATCH
+                    # epoch, re-using the submit-time signatures
+                    self._cache_fill(req, result, fl.epoch)
         now = self._clock()
         self._hist("demux", bucket).observe((now - t_demux0) * 1e3)
         e2e = self._hist("e2e", bucket)

@@ -32,12 +32,23 @@ from raft_tpu.spatial.ann.common import (
 )
 
 __all__ = [
+    "GROUPED_SCOPES",
     "IVFFlatParams",
     "IVFFlatIndex",
     "ivf_flat_build",
     "ivf_flat_search",
     "ivf_flat_search_grouped",
 ]
+
+
+# the named parts of the grouped search program (``jax.named_scope``
+# names: each device op's metadata carries its part, so a profile can sum
+# device time per part; docs/observability.md "Spans and scopes")
+SCOPE_PROBE = "ivf.probe"            # coarse probe, probe-map inversion
+SCOPE_LIST_SLABS = "ivf.list_slabs"  # per-list slabs, query gather + pad
+SCOPE_SCAN = "ivf.scan"              # the scan kernel or XLA tile math
+SCOPE_MERGE = "ivf.merge"            # regroup, top-c, rerank, final top-k
+GROUPED_SCOPES = (SCOPE_PROBE, SCOPE_LIST_SLABS, SCOPE_SCAN, SCOPE_MERGE)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -255,7 +266,6 @@ def _grouped_impl(index, q, k, n_probes, qcap, list_block, probes=None,
     nq, d = q.shape
     p = n_probes
     f32 = jnp.float32
-    qf = q.astype(f32)
 
     def dq_rows(rows_f32):
         """Affine-dequantize gathered/sliced rows when the scan runs in
@@ -271,56 +281,64 @@ def _grouped_impl(index, q, k, n_probes, qcap, list_block, probes=None,
         coarse_probe, invert_probe_map_ranked,
     )
 
-    if probes is None:
-        probes, _ = coarse_probe(qf, index.centroids, p)     # (nq, p)
-    # invert the probe map: for each list, the (padded) set of queries
-    # probing it (shared grouped-search machinery, common.py)
-    qmat, rmat, l_flat, slot = invert_probe_map_ranked(
-        probes, n_lists, qcap
-    )
+    with jax.named_scope(SCOPE_PROBE):
+        qf = q.astype(f32)
+        if probes is None:
+            probes, _ = coarse_probe(qf, index.centroids, p)  # (nq, p)
+        # invert the probe map: for each list, the (padded) set of
+        # queries probing it (shared grouped-search machinery, common.py)
+        qmat, rmat, l_flat, slot = invert_probe_map_ranked(
+            probes, n_lists, qcap
+        )
 
-    q_pad = jnp.concatenate([qf, jnp.zeros((1, d), f32)])    # sentinel query
-    qn_pad = jnp.concatenate(
-        [jnp.sum(qf * qf, axis=1), jnp.zeros((1,), f32)]
-    )
+    with jax.named_scope(SCOPE_LIST_SLABS):
+        q_pad = jnp.concatenate([qf, jnp.zeros((1, d), f32)])  # sentinel
+        qn_pad = jnp.concatenate(
+            [jnp.sum(qf * qf, axis=1), jnp.zeros((1,), f32)]
+        )
 
     def block_fn(lblk):                                      # (LB,) list ids
-        qids = qmat[lblk]                                    # (LB, qcap)
-        qv = q_pad[qids]                                     # (LB, qcap, d)
-        qnv = qn_pad[qids]                                   # (LB, qcap)
-        # lists are CONTIGUOUS in sorted storage: read each as one
-        # dynamic_slice slab instead of row-granular list_index gathers
-        # (d*4-byte rows measured ~50x slower at 10M-scale shapes)
-        offs = storage.list_offsets[lblk]                    # (LB,)
-        szs = storage.list_sizes[lblk]
-        o_c = jnp.minimum(offs, storage.n + 1 - L)           # slice clamp
-        mv = dq_rows(jax.vmap(
-            lambda s: lax.dynamic_slice(index.data_sorted, (s, 0), (L, d))
-        )(o_c).astype(f32))                                  # (LB, L, d)
-        pos = o_c[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
-        in_list = (pos >= offs[:, None]) & (pos < (offs + szs)[:, None])
-        if row_mask is not None:
-            in_list = in_list & (row_mask[pos] > 0)
-        mn = jnp.sum(mv * mv, axis=2)                        # (LB, L)
-        dots = jnp.einsum(
-            "bqd,bld->bql", qv, mv, preferred_element_type=f32,
-            precision=lax.Precision.HIGHEST,
-        )  # MXU batched; HIGHEST keeps f32 operands un-rounded so grouped
-        #    scores match the per-query path bit-for-near (measured: DEFAULT
-        #    rounds operands and perturbs ~1e-3 of neighbor orderings)
-        d2 = qnv[:, :, None] + mn[:, None, :] - 2.0 * dots
-        invalid = (qids >= nq)[:, :, None] | (~in_list)[:, None, :]
-        d2 = jnp.where(invalid, jnp.inf, d2)
-        # the INTENTIONAL legacy materialized-tile scan, kept as the
-        # use_pallas=False bit-stable engine and the CPU fallback — the
-        # Pallas sub-chunk-min path above it is the fixed spelling
-        # (docs/static_analysis.md "Baseline burn-down"):
-        vals, sel = lax.top_k(-d2, k)  # jaxlint: disable=wide-distance-materialize
-        # k-wide selection remap, not a LUT gather:
-        memp = jnp.take_along_axis(  # jaxlint: disable=adc-gather
-            jnp.broadcast_to(pos[:, None, :], d2.shape), sel, axis=2
-        )
-        return -vals, memp
+        with jax.named_scope(SCOPE_LIST_SLABS):
+            qids = qmat[lblk]                                # (LB, qcap)
+            qv = q_pad[qids]                                 # (LB, qcap, d)
+            qnv = qn_pad[qids]                               # (LB, qcap)
+            # lists are CONTIGUOUS in sorted storage: read each as one
+            # dynamic_slice slab instead of row-granular list_index
+            # gathers (d*4-byte rows measured ~50x slower at 10M-scale
+            # shapes)
+            offs = storage.list_offsets[lblk]                # (LB,)
+            szs = storage.list_sizes[lblk]
+            o_c = jnp.minimum(offs, storage.n + 1 - L)       # slice clamp
+            mv = dq_rows(jax.vmap(
+                lambda s: lax.dynamic_slice(index.data_sorted, (s, 0),
+                                            (L, d))
+            )(o_c).astype(f32))                              # (LB, L, d)
+            pos = o_c[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
+            in_list = (pos >= offs[:, None]) & (pos < (offs + szs)[:, None])
+            if row_mask is not None:
+                in_list = in_list & (row_mask[pos] > 0)
+        with jax.named_scope(SCOPE_SCAN):
+            mn = jnp.sum(mv * mv, axis=2)                    # (LB, L)
+            dots = jnp.einsum(
+                "bqd,bld->bql", qv, mv, preferred_element_type=f32,
+                precision=lax.Precision.HIGHEST,
+            )  # MXU batched; HIGHEST keeps f32 operands un-rounded so
+            #    grouped scores match the per-query path bit-for-near
+            #    (measured: DEFAULT rounds operands and perturbs ~1e-3 of
+            #    neighbor orderings)
+            d2 = qnv[:, :, None] + mn[:, None, :] - 2.0 * dots
+            invalid = (qids >= nq)[:, :, None] | (~in_list)[:, None, :]
+            d2 = jnp.where(invalid, jnp.inf, d2)
+            # the INTENTIONAL legacy materialized-tile scan, kept as the
+            # use_pallas=False bit-stable engine and the CPU fallback —
+            # the Pallas sub-chunk-min path above it is the fixed
+            # spelling (docs/static_analysis.md "Baseline burn-down"):
+            vals, sel = lax.top_k(-d2, k)  # jaxlint: disable=wide-distance-materialize
+            # k-wide selection remap, not a LUT gather:
+            memp = jnp.take_along_axis(  # jaxlint: disable=adc-gather
+                jnp.broadcast_to(pos[:, None, :], d2.shape), sel, axis=2
+            )
+            return -vals, memp
 
     use_kernel = bool(use_pallas)
     if use_kernel:
@@ -358,42 +376,45 @@ def _grouped_impl(index, q, k, n_probes, qcap, list_block, probes=None,
         # tiny indexes whose whole slab is shorter than one padded list
         # window: extend the slab so the clamped dynamic_slice stays in
         # range (static condition — big indexes never pay the copy)
-        data_src = (
-            index.data_sorted if rows_pad == rows
-            else jnp.pad(index.data_sorted,
-                         ((0, rows_pad - rows), (0, 0)))
-        )
+        with jax.named_scope(SCOPE_LIST_SLABS):
+            data_src = (
+                index.data_sorted if rows_pad == rows
+                else jnp.pad(index.data_sorted,
+                             ((0, rows_pad - rows), (0, 0)))
+            )
 
         def block_fn_pallas(lblk):            # (LB,) list ids
-            qids = qmat[lblk]                                # (LB, qcap)
-            qv = q_pad[qids]                                 # (LB, qcap, d)
-            if q_kpad > qcap:
-                qv = jnp.pad(qv, ((0, 0), (0, q_kpad - qcap), (0, 0)))
-            offs = storage.list_offsets[lblk]                # (LB,)
-            szs = storage.list_sizes[lblk]
-            o_c = jnp.minimum(offs, rows_pad - l_pad)        # slice clamp
-            slabs_t = jax.vmap(
-                lambda s: lax.dynamic_slice(data_src, (s, 0), (l_pad, d))
-            )(o_c).transpose(0, 2, 1)                        # (LB, d, l_pad)
-            lo = offs - o_c
-            bounds = jnp.stack([lo, lo + szs], axis=1)       # (LB, 2)
-            if dequant is None:
-                mins = kmod.flat_scan_subchunk_min(
-                    qv, slabs_t, bounds,
-                    interpret=pallas_interpret, l_tile=l_tile,
-                )
-            else:
-                mins = kmod.sq_scan_subchunk_min(
-                    qv, slabs_t.astype(jnp.int8), bounds,
-                    dequant[0], dequant[1],
-                    interpret=pallas_interpret, l_tile=l_tile,
-                )
-            mins = mins[:, :qcap]                            # (LB, qcap, nsc)
-            # positions are NOT returned: a sub-chunk's slab base is
-            # fully derivable from (probe slot, chunk index) after
-            # selection, so the kernel path pools VALUES ONLY — half
-            # the pool memory and scatter traffic of the legacy path
-            return mins
+            with jax.named_scope(SCOPE_LIST_SLABS):
+                qids = qmat[lblk]                            # (LB, qcap)
+                qv = q_pad[qids]                             # (LB, qcap, d)
+                if q_kpad > qcap:
+                    qv = jnp.pad(qv, ((0, 0), (0, q_kpad - qcap), (0, 0)))
+                offs = storage.list_offsets[lblk]            # (LB,)
+                szs = storage.list_sizes[lblk]
+                o_c = jnp.minimum(offs, rows_pad - l_pad)    # slice clamp
+                slabs_t = jax.vmap(
+                    lambda s: lax.dynamic_slice(data_src, (s, 0),
+                                                (l_pad, d))
+                )(o_c).transpose(0, 2, 1)                    # (LB, d, l_pad)
+                lo = offs - o_c
+                bounds = jnp.stack([lo, lo + szs], axis=1)   # (LB, 2)
+            with jax.named_scope(SCOPE_SCAN):
+                if dequant is None:
+                    mins = kmod.flat_scan_subchunk_min(
+                        qv, slabs_t, bounds,
+                        interpret=pallas_interpret, l_tile=l_tile,
+                    )
+                else:
+                    mins = kmod.sq_scan_subchunk_min(
+                        qv, slabs_t.astype(jnp.int8), bounds,
+                        dequant[0], dequant[1],
+                        interpret=pallas_interpret, l_tile=l_tile,
+                    )
+                # positions are NOT returned: a sub-chunk's slab base is
+                # fully derivable from (probe slot, chunk index) after
+                # selection, so the kernel path pools VALUES ONLY — half
+                # the pool memory and scatter traffic of the legacy path
+                return mins[:, :qcap]                        # (LB, qcap, nsc)
 
         width, scan_fn = nsc, block_fn_pallas
     else:
@@ -405,10 +426,6 @@ def _grouped_impl(index, q, k, n_probes, qcap, list_block, probes=None,
     # of shrinking list_block, which collapses to 1-list blocks when
     # n_lists is prime-ish (e.g. after oversized-list splitting)
     nl_pad = -(-n_lists // list_block) * list_block
-    lids = jnp.minimum(
-        jnp.arange(nl_pad, dtype=jnp.int32), n_lists - 1
-    ).reshape(-1, list_block)
-
     if stream_partials is None:
         # auto: stream once materialized (n_lists, qcap, width) partials
         # pass ~2 GB (same skewed-qcap blow-up bound as the PQ grouped
@@ -416,125 +433,139 @@ def _grouped_impl(index, q, k, n_probes, qcap, list_block, probes=None,
         # positions), hence the smaller footprint
         per_entry = 4 if use_kernel else 8
         stream_partials = n_lists * qcap * width * per_entry > (1 << 31)
-    if stream_partials:
-        if use_kernel:
-            def scan_body_v(pvc, lblk):
-                v = scan_fn(lblk)
-                qi, ri = qmat[lblk], rmat[lblk]      # sentinels drop
-                return pvc.at[qi, ri].set(v, mode="drop"), None
 
-            pv, _ = lax.scan(
-                scan_body_v,
-                jnp.full((nq, p, width), jnp.inf, jnp.float32), lids,
-            )
-            pv, pm = pv.reshape(nq, p * width), None
+    # the list loop (and its bookkeeping) is the scan; each body's slab
+    # work and streamed scatter name their own parts
+    with jax.named_scope(SCOPE_SCAN):
+        lids = jnp.minimum(
+            jnp.arange(nl_pad, dtype=jnp.int32), n_lists - 1
+        ).reshape(-1, list_block)
+        if stream_partials:
+            if use_kernel:
+                def scan_body_v(pvc, lblk):
+                    v = scan_fn(lblk)
+                    with jax.named_scope(SCOPE_MERGE):
+                        qi, ri = qmat[lblk], rmat[lblk]  # sentinels drop
+                        return pvc.at[qi, ri].set(v, mode="drop"), None
+
+                with jax.named_scope(SCOPE_MERGE):
+                    init_v = jnp.full((nq, p, width), jnp.inf, jnp.float32)
+                pv, _ = lax.scan(scan_body_v, init_v, lids)
+            else:
+                def scan_body(carry, lblk):
+                    pvc, pmc = carry
+                    v, mp = scan_fn(lblk)
+                    with jax.named_scope(SCOPE_MERGE):
+                        qi, ri = qmat[lblk], rmat[lblk]  # sentinels drop
+                        pvc = pvc.at[qi, ri].set(v, mode="drop")
+                        pmc = pmc.at[qi, ri].set(mp, mode="drop")
+                        return (pvc, pmc), None
+
+                with jax.named_scope(SCOPE_MERGE):
+                    init = (
+                        jnp.full((nq, p, k), jnp.inf, jnp.float32),
+                        jnp.full((nq, p, k), storage.n, jnp.int32),
+                    )
+                (pv, pm), _ = lax.scan(scan_body, init, lids)
+        elif use_kernel:
+            vals = lax.map(scan_fn, lids)
         else:
-            def scan_body(carry, lblk):
-                pvc, pmc = carry
-                v, mp = scan_fn(lblk)
-                qi, ri = qmat[lblk], rmat[lblk]      # sentinels drop
-                pvc = pvc.at[qi, ri].set(v, mode="drop")
-                pmc = pmc.at[qi, ri].set(mp, mode="drop")
-                return (pvc, pmc), None
+            vals, mem = lax.map(scan_fn, lids)
 
-            init = (
-                jnp.full((nq, p, k), jnp.inf, jnp.float32),
-                jnp.full((nq, p, k), storage.n, jnp.int32),
+    with jax.named_scope(SCOPE_MERGE):
+        if stream_partials:
+            pv = pv.reshape(nq, p * width)
+            pm = None if use_kernel else pm.reshape(nq, p * k)
+        elif use_kernel:
+            vals = vals.reshape(nl_pad, qcap, width)[:n_lists]
+            # values-only regroup (the slot inverse of regroup_pairs)
+            ok = slot < qcap
+            safe_slot = jnp.minimum(slot, qcap - 1)
+            pv = jnp.where(
+                ok[:, None], vals[l_flat, safe_slot], jnp.inf
+            ).reshape(nq, p * width)
+            pm = None
+        else:
+            vals = vals.reshape(nl_pad, qcap, k)[:n_lists]
+            mem = mem.reshape(nl_pad, qcap, k)[:n_lists]
+
+            # per-pair result gather (original query-major order), then
+            # the final selection
+            from raft_tpu.spatial.ann.common import regroup_pairs
+
+            pv, pm = regroup_pairs(vals, mem, l_flat, slot, nq, p, qcap)
+
+        if use_kernel:
+            # kernel path: pool entries are SUB-CHUNK minima. Select the
+            # top-c sub-chunks — the fused_knn chunk-cover argument at 8-row
+            # granularity: every rank-c row lives in a sub-chunk whose
+            # minimum is <= the c-th best scanned value, so the selected
+            # sub-chunks' rows cover the top-c rows — then rescore their
+            # rows with EXACT f32 at HIGHEST precision (the distance tile
+            # never round-trips HBM; returned distances are exact). Clamp
+            # to the pool width LAST: a large k (> p*width) must not ask
+            # top_k for more sub-chunks than exist — the clamped pool still
+            # covers k rows (c*8 = p*l_pad >= p*max_list >= k, the
+            # check_candidate_pool precondition).
+            from raft_tpu.spatial.ann.common import (
+                map_query_blocks, score_l2_candidates, select_candidates,
             )
-            (pv, pm), _ = lax.scan(scan_body, init, lids)
-            pv = pv.reshape(nq, p * k)
-            pm = pm.reshape(nq, p * k)
-    elif use_kernel:
-        vals = lax.map(scan_fn, lids)
-        vals = vals.reshape(nl_pad, qcap, width)[:n_lists]
-        # values-only regroup (the slot inverse of regroup_pairs)
-        ok = slot < qcap
-        safe_slot = jnp.minimum(slot, qcap - 1)
-        pv = jnp.where(
-            ok[:, None], vals[l_flat, safe_slot], jnp.inf
-        ).reshape(nq, p * width)
-        pm = None
-    else:
-        vals, mem = lax.map(scan_fn, lids)
-        vals = vals.reshape(nl_pad, qcap, k)[:n_lists]
-        mem = mem.reshape(nl_pad, qcap, k)[:n_lists]
 
-        # per-pair result gather (original query-major order), then final
-        from raft_tpu.spatial.ann.common import regroup_pairs
-
-        pv, pm = regroup_pairs(vals, mem, l_flat, slot, nq, p, qcap)
-
-    if use_kernel:
-        # kernel path: pool entries are SUB-CHUNK minima. Select the
-        # top-c sub-chunks — the fused_knn/PR 6 cover argument at 8-row
-        # granularity: every rank-c row lives in a sub-chunk whose
-        # minimum is <= the c-th best scanned value, so the selected
-        # sub-chunks' rows cover the top-c rows — then rescore their
-        # rows with EXACT f32 at HIGHEST precision (the distance tile
-        # never round-trips HBM; returned distances are exact). Clamp
-        # to the pool width LAST: a large k (> p*width) must not ask
-        # top_k for more sub-chunks than exist — the clamped pool still
-        # covers k rows (c*8 = p*l_pad >= p*max_list >= k, the
-        # check_candidate_pool precondition).
-        from raft_tpu.spatial.ann.common import (
-            map_query_blocks, score_l2_candidates, select_candidates,
-        )
-
-        c = min(p * width, max(k, int(math.ceil(rerank_ratio * k))))
-        nv, cpos = lax.top_k(-pv, c)
-        nadc = -nv                                           # (nq, c)
-        cpos = cpos.astype(jnp.int32)
-        # slab positions are DERIVED, not pooled: pool index -> (probe
-        # slot, chunk), and the sub-chunk's base replays the block's
-        # clamped dynamic-slice origin o_c = min(offset, rows_pad-l_pad)
-        offs_q = storage.list_offsets[probes]                # (nq, p)
-        szs_q = storage.list_sizes[probes]
-        slot_sel = cpos // width
-        off_sel = jnp.take_along_axis(offs_q, slot_sel, axis=1)
-        end_sel = off_sel + jnp.take_along_axis(szs_q, slot_sel, axis=1)
-        base_sel = (
-            jnp.minimum(off_sel, rows_pad - l_pad)
-            + sub * (cpos % width)
-        )                                                    # (nq, c)
-        # per-row validity: a sub-chunk window can overhang its list's
-        # tail into the NEXT list's slab rows — mask against the exact
-        # [offset, offset+size) range of the probe slot it came from
-        rows_sel = base_sel[:, :, None] + jnp.arange(sub, dtype=jnp.int32)
-        validf = (
-            (rows_sel >= off_sel[:, :, None])
-            & (rows_sel < end_sel[:, :, None])
-            & (jnp.isfinite(nadc)
-               & (nadc < scan_core.BIG))[:, :, None]
-        )
-        if row_mask is not None:
-            # tombstones are applied per ROW at the rerank tail on the
-            # kernel path (the in-kernel sub-chunk minima are unmasked)
-            validf = validf & (
-                row_mask[jnp.clip(rows_sel, 0, storage.n)] > 0
+            c = min(p * width, max(k, int(math.ceil(rerank_ratio * k))))
+            nv, cpos = lax.top_k(-pv, c)
+            nadc = -nv                                           # (nq, c)
+            cpos = cpos.astype(jnp.int32)
+            # slab positions are DERIVED, not pooled: pool index -> (probe
+            # slot, chunk), and the sub-chunk's base replays the block's
+            # clamped dynamic-slice origin o_c = min(offset, rows_pad-l_pad)
+            offs_q = storage.list_offsets[probes]                # (nq, p)
+            szs_q = storage.list_sizes[probes]
+            slot_sel = cpos // width
+            off_sel = jnp.take_along_axis(offs_q, slot_sel, axis=1)
+            end_sel = off_sel + jnp.take_along_axis(szs_q, slot_sel, axis=1)
+            base_sel = (
+                jnp.minimum(off_sel, rows_pad - l_pad)
+                + sub * (cpos % width)
+            )                                                    # (nq, c)
+            # per-row validity: a sub-chunk window can overhang its list's
+            # tail into the NEXT list's slab rows — mask against the exact
+            # [offset, offset+size) range of the probe slot it came from
+            rows_sel = base_sel[:, :, None] + jnp.arange(sub, dtype=jnp.int32)
+            validf = (
+                (rows_sel >= off_sel[:, :, None])
+                & (rows_sel < end_sel[:, :, None])
+                & (jnp.isfinite(nadc)
+                   & (nadc < scan_core.BIG))[:, :, None]
             )
-        validf = validf.reshape(nq, c * sub)
-        rpos = rows_sel.reshape(nq, c * sub)
+            if row_mask is not None:
+                # tombstones are applied per ROW at the rerank tail on the
+                # kernel path (the in-kernel sub-chunk minima are unmasked)
+                validf = validf & (
+                    row_mask[jnp.clip(rows_sel, 0, storage.n)] > 0
+                )
+            validf = validf.reshape(nq, c * sub)
+            rpos = rows_sel.reshape(nq, c * sub)
 
-        def rerank_blk(args):
-            qb, rp, vl = args
-            raw = dq_rows(
-                data_src[jnp.clip(rp, 0, storage.n)].astype(f32)
-            )
-            exact = score_l2_candidates(qb, raw, vl & (rp < storage.n))
-            return select_candidates(storage, rp, exact, k)
+            def rerank_blk(args):
+                qb, rp, vl = args
+                raw = dq_rows(
+                    data_src[jnp.clip(rp, 0, storage.n)].astype(f32)
+                )
+                exact = score_l2_candidates(qb, raw, vl & (rp < storage.n))
+                return select_candidates(storage, rp, exact, k)
 
-        # block the (blk_q, c*8, d) raw-row gather over queries so the
-        # 8x-wider kernel-path pool never materializes a multi-GB
-        # transient at serving batch sizes (zero-padded rows compute on
-        # all-invalid candidates and are sliced away)
-        blk_q = max(8, min(nq, _RERANK_BLOCK_BYTES // (c * sub * d * 4)))
-        return map_query_blocks(rerank_blk, (qf, rpos, validf), blk_q)
+            # block the (blk_q, c*8, d) raw-row gather over queries so the
+            # 8x-wider kernel-path pool never materializes a multi-GB
+            # transient at serving batch sizes (zero-padded rows compute on
+            # all-invalid candidates and are sliced away)
+            blk_q = max(8, min(nq, _RERANK_BLOCK_BYTES // (c * sub * d * 4)))
+            return map_query_blocks(rerank_blk, (qf, rpos, validf), blk_q)
 
-    fvals, fpos = lax.top_k(-pv, k)
-    fmem = jnp.take_along_axis(pm, fpos, axis=1)
-    ids = storage.sorted_ids[jnp.clip(fmem, 0, storage.n - 1)]
-    ids = jnp.where(jnp.isfinite(-fvals), ids, -1).astype(jnp.int32)
-    return -fvals, ids
+        fvals, fpos = lax.top_k(-pv, k)
+        fmem = jnp.take_along_axis(pm, fpos, axis=1)
+        ids = storage.sorted_ids[jnp.clip(fmem, 0, storage.n - 1)]
+        ids = jnp.where(jnp.isfinite(-fvals), ids, -1).astype(jnp.int32)
+        return -fvals, ids
 
 
 def ivf_flat_search_grouped(

@@ -43,7 +43,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from raft_tpu.distance.distance_type import DistanceType
 
-__all__ = ["fused_l2_knn", "fused_knn_supported"]
+__all__ = ["fused_l2_knn", "fused_knn_supported", "KNN_SCOPES"]
+
+# the named parts of the brute-force program (``jax.named_scope`` names:
+# each device op's metadata carries its part, so a profile can sum device
+# time per part; docs/observability.md "Spans and scopes")
+SCOPE_PREPARE = "knn.prepare"        # row pad, casts, norms, query pads
+SCOPE_CHUNK_MINS = "knn.chunk_mins"  # the phase-1 chunk-min kernel
+SCOPE_SELECT = "knn.select"          # top-c chunks by their minima
+SCOPE_RESCORE = "knn.rescore"        # DMA or gather rescore, final top-k
+KNN_SCOPES = (SCOPE_PREPARE, SCOPE_CHUNK_MINS, SCOPE_SELECT, SCOPE_RESCORE)
 
 _CHUNK = 128  # lane width: one chunk-min per vreg row per reduce
 
@@ -237,26 +246,27 @@ def _fused_l2_knn_impl(
 ) -> Tuple[jax.Array, jax.Array]:
     m, d = queries.shape
     n = index.shape[0]
-    q = jnp.asarray(queries, jnp.float32)
-    # The index keeps its storage dtype (bf16 storage halves HBM for the
-    # 10M x 768 regime — no f32 copy is ever materialized; accumulations
-    # below are f32 via preferred_element_type).
-    y = jnp.asarray(index)
-
     npad = _round_up(n, bn)
     # Padded rows score +BIG in phase 1 (never win a chunk) and +BIG in
     # phase 2 rescoring (never selected); BIG is finite to keep inf-inf
     # NaNs out of the VPU.
     BIG = jnp.float32(1e30)
-    # trace-level skip when already aligned: a zero-width jnp.pad of a
-    # multi-GB index is not reliably elided and would copy it (fatal for
-    # the HBM-resident big-index regime)
-    yp = y if npad == n else jnp.pad(y, ((0, npad - n), (0, 0)))
+    with jax.named_scope(SCOPE_PREPARE):
+        q = jnp.asarray(queries, jnp.float32)
+        # The index keeps its storage dtype (bf16 storage halves HBM for
+        # the 10M x 768 regime — no f32 copy is ever materialized;
+        # accumulations below are f32 via preferred_element_type).
+        y = jnp.asarray(index)
+        # trace-level skip when already aligned: a zero-width jnp.pad of a
+        # multi-GB index is not reliably elided and would copy it (fatal
+        # for the HBM-resident big-index regime)
+        yp = y if npad == n else jnp.pad(y, ((0, npad - n), (0, 0)))
 
-    cmins = _chunk_mins(
-        q, yp, n_valid=n, bm=bm, bn=bn,
-        compute_dtype=compute_dtype, interpret=interpret,
-    )  # (m, nC)
+    with jax.named_scope(SCOPE_CHUNK_MINS):
+        cmins = _chunk_mins(
+            q, yp, n_valid=n, bm=bm, bn=bn,
+            compute_dtype=compute_dtype, interpret=interpret,
+        )  # (m, nC)
 
     # phase 2: top-c chunks per query -> gather WHOLE chunks -> exact rescore.
     # c = k + extra_chunks: with exact arithmetic the top-k chunks suffice
@@ -300,51 +310,59 @@ def _fused_l2_knn_impl(
         and smem_rows >= _QBLK
     )
     if use_dma:
-        _, cids = lax.top_k(-cmins, cpad)               # (m, cpad)
-        qpad = q if mp8 == m else jnp.pad(q, ((0, mp8 - m), (0, 0)))
-        cpds = cids if mp8 == m else jnp.pad(cids, ((0, mp8 - m), (0, 0)))
-        cpds = cpds.astype(jnp.int32)
-        blk = smem_rows // _QBLK * _QBLK
-        if mp8 <= blk:
-            scores = _rescore_scores(
-                qpad, cpds, yp, c=cpad, interpret=interpret
-            )[:m]
-        else:
-            # batches past the per-call budget run the SAME kernel via
-            # lax.map over uniform blk-row tiles: one compiled program
-            # regardless of m (an unrolled Python loop would emit one
-            # pallas_call per tile and blow up the HLO at large m)
-            tiles = _cdiv(mp8, blk)
-            pad2 = tiles * blk - mp8
-            qt = jnp.pad(qpad, ((0, pad2), (0, 0))).reshape(tiles, blk, d)
-            ct = jnp.pad(cpds, ((0, pad2), (0, 0))).reshape(
-                tiles, blk, cpad
-            )
-            scores = jax.lax.map(
-                lambda t: _rescore_scores(
-                    t[0], t[1], yp, c=cpad, interpret=interpret
-                ),
-                (qt, ct),
-            ).reshape(tiles * blk, cpad * _CHUNK)[:m]   # (m, cpad*128)
-        qn = jnp.sum(q * q, axis=-1)
-        d2 = qn[:, None] + scores
-        col = (cids[:, :, None] * _CHUNK
-               + jnp.arange(_CHUNK)[None, None, :]).reshape(m, cpad * _CHUNK)
-        d2 = jnp.where(col >= n, BIG, d2)
-        negv, pos = lax.top_k(-d2, k)
-        vals = -negv
-        idxs = jnp.take_along_axis(col, pos, axis=1)
-        vals = jnp.maximum(vals, 0.0)
-        if metric == DistanceType.L2SqrtExpanded:
-            vals = jnp.sqrt(vals)
-        return vals, idxs.astype(jnp.int32)
+        with jax.named_scope(SCOPE_SELECT):
+            _, cids = lax.top_k(-cmins, cpad)           # (m, cpad)
+            cpds = (cids if mp8 == m
+                    else jnp.pad(cids, ((0, mp8 - m), (0, 0))))
+            cpds = cpds.astype(jnp.int32)
+        with jax.named_scope(SCOPE_PREPARE):
+            qpad = q if mp8 == m else jnp.pad(q, ((0, mp8 - m), (0, 0)))
+            qn = jnp.sum(q * q, axis=-1)
+        with jax.named_scope(SCOPE_RESCORE):
+            blk = smem_rows // _QBLK * _QBLK
+            if mp8 <= blk:
+                scores = _rescore_scores(
+                    qpad, cpds, yp, c=cpad, interpret=interpret
+                )[:m]
+            else:
+                # batches past the per-call budget run the SAME kernel
+                # via lax.map over uniform blk-row tiles: one compiled
+                # program regardless of m (an unrolled Python loop would
+                # emit one pallas_call per tile and blow up the HLO at
+                # large m)
+                tiles = _cdiv(mp8, blk)
+                pad2 = tiles * blk - mp8
+                qt = jnp.pad(qpad, ((0, pad2), (0, 0))).reshape(
+                    tiles, blk, d)
+                ct = jnp.pad(cpds, ((0, pad2), (0, 0))).reshape(
+                    tiles, blk, cpad
+                )
+                scores = jax.lax.map(
+                    lambda t: _rescore_scores(
+                        t[0], t[1], yp, c=cpad, interpret=interpret
+                    ),
+                    (qt, ct),
+                ).reshape(tiles * blk, cpad * _CHUNK)[:m]  # (m, cpad*128)
+            d2 = qn[:, None] + scores
+            col = (cids[:, :, None] * _CHUNK
+                   + jnp.arange(_CHUNK)[None, None, :]
+                   ).reshape(m, cpad * _CHUNK)
+            d2 = jnp.where(col >= n, BIG, d2)
+            negv, pos = lax.top_k(-d2, k)
+            vals = -negv
+            idxs = jnp.take_along_axis(col, pos, axis=1)
+            vals = jnp.maximum(vals, 0.0)
+            if metric == DistanceType.L2SqrtExpanded:
+                vals = jnp.sqrt(vals)
+            return vals, idxs.astype(jnp.int32)
 
     # XLA gather fallback (interpret-pinned variants, tiny chunk counts).
     # Gather granularity matters: one chunk = 128 contiguous index rows
     # (a 64 KB row after the reshape below), which is the efficient TPU
     # gather regime — per-row gathers of the same candidates measured ~7x
     # slower.
-    _, cids = lax.top_k(-cmins, c)                      # (m, c)
+    with jax.named_scope(SCOPE_SELECT):
+        _, cids = lax.top_k(-cmins, c)                  # (m, c)
 
     # Chunk-granular gather ((nC, 128*d) reshape) is the fast path — one
     # 64 KB contiguous row per candidate chunk, measured ~7x per-row
@@ -356,24 +374,29 @@ def _fused_l2_knn_impl(
         if gather_rows is not None
         else npad * d * y.dtype.itemsize > (2 << 30)
     )
-    if not big_index:
-        ychunks = yp.reshape(nC, _CHUNK * d)
-    # caller-precomputed norms skip a full index read here — the analog
-    # of the reference storing norms with the index
-    # (knn_brute_force_faiss.cuh:318-330 norms argument)
-    yn = (
-        jnp.asarray(index_norms, jnp.float32)
-        if index_norms is not None
-        else jnp.einsum("nd,nd->n", y, y, preferred_element_type=jnp.float32)
-    )
-    ynp = yn if npad == n else jnp.pad(yn, (0, npad - n), constant_values=BIG)
-    ynchunks = ynp.reshape(nC, _CHUNK)
-
-    qn = jnp.sum(q * q, axis=-1)
     mp2 = _round_up(m, bq2)
-    qb = jnp.pad(q, ((0, mp2 - m), (0, 0))).reshape(mp2 // bq2, bq2, d)
-    qnb = jnp.pad(qn, (0, mp2 - m)).reshape(mp2 // bq2, bq2)
-    cb = jnp.pad(cids, ((0, mp2 - m), (0, 0))).reshape(mp2 // bq2, bq2, c)
+    with jax.named_scope(SCOPE_PREPARE):
+        if not big_index:
+            ychunks = yp.reshape(nC, _CHUNK * d)
+        # caller-precomputed norms skip a full index read here — the
+        # analog of the reference storing norms with the index
+        # (knn_brute_force_faiss.cuh:318-330 norms argument)
+        yn = (
+            jnp.asarray(index_norms, jnp.float32)
+            if index_norms is not None
+            else jnp.einsum("nd,nd->n", y, y,
+                            preferred_element_type=jnp.float32)
+        )
+        ynp = (yn if npad == n
+               else jnp.pad(yn, (0, npad - n), constant_values=BIG))
+        ynchunks = ynp.reshape(nC, _CHUNK)
+
+        qn = jnp.sum(q * q, axis=-1)
+        qb = jnp.pad(q, ((0, mp2 - m), (0, 0))).reshape(mp2 // bq2, bq2, d)
+        qnb = jnp.pad(qn, (0, mp2 - m)).reshape(mp2 // bq2, bq2)
+    with jax.named_scope(SCOPE_SELECT):
+        cb = jnp.pad(cids, ((0, mp2 - m), (0, 0))).reshape(
+            mp2 // bq2, bq2, c)
 
     def rescore(args):
         qblk, qnblk, cblk = args                   # (bq2, d), (bq2,), (bq2, c)
@@ -406,14 +429,15 @@ def _fused_l2_knn_impl(
         idx = which * _CHUNK + pos % _CHUNK
         return -vals, idx
 
-    vals, idxs = lax.map(rescore, (qb, qnb, cb))
-    vals = vals.reshape(mp2, k)[:m]
-    idxs = idxs.reshape(mp2, k)[:m]
+    with jax.named_scope(SCOPE_RESCORE):
+        vals, idxs = lax.map(rescore, (qb, qnb, cb))
+        vals = vals.reshape(mp2, k)[:m]
+        idxs = idxs.reshape(mp2, k)[:m]
 
-    vals = jnp.maximum(vals, 0.0)
-    if metric == DistanceType.L2SqrtExpanded:
-        vals = jnp.sqrt(vals)
-    return vals, idxs.astype(jnp.int32)
+        vals = jnp.maximum(vals, 0.0)
+        if metric == DistanceType.L2SqrtExpanded:
+            vals = jnp.sqrt(vals)
+        return vals, idxs.astype(jnp.int32)
 
 
 _L2_FAMILY = (

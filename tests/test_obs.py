@@ -7,6 +7,7 @@ retrace census. Everything host-side — the one jitted program here is
 a 3-element add for the census — so the whole file stays cheap in
 tier-1."""
 
+import contextlib
 import json
 import threading
 import time
@@ -25,7 +26,7 @@ from raft_tpu.obs import FlightRecorder, MetricRegistry, program_census
 from raft_tpu.obs import metrics as obsm
 from raft_tpu.obs.capture import ProfileTrigger
 from raft_tpu.serving import ServingExecutor
-from raft_tpu.serving.executor import STAGES, ExecutorStats
+from raft_tpu.serving.executor import SPANS, STAGES, ExecutorStats
 from raft_tpu.testing import load
 
 D = 4
@@ -204,6 +205,56 @@ def _host_dispatch(batch, **_rt):
     return (batch * 2.0, np.argsort(batch, axis=1).astype(np.int32))
 
 
+def _wait_for(cond, timeout_s=10.0):
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < deadline, "condition never held"
+        time.sleep(0.005)
+
+
+class TestExecutorSpans:
+    def test_spans_once_per_batch_in_order_inside_a_capture(self,
+                                                            tmp_path):
+        """Inside ``annotate.start_trace`` (a real CPU capture) each
+        batch emits the five serving spans once, in hop order, tied by
+        their ``batch_id`` stat; pack also carries bucket and
+        n_requests."""
+        from jax.profiler import ProfileData
+
+        prev = annotate_mod.set_profiling(False)
+        try:
+            annotate_mod.start_trace(str(tmp_path))
+            try:
+                with ServingExecutor(_host_dispatch, (4, 8), dim=D,
+                                     flush_age_s=0.0,
+                                     registry=MetricRegistry()) as ex:
+                    for rows in (1, 3, 6):
+                        ex.submit(np.ones((rows, D), np.float32)).result(
+                            timeout=30)
+            finally:
+                annotate_mod.stop_trace()
+            assert not annotate_mod.profiling_enabled()
+        finally:
+            annotate_mod.set_profiling(prev)
+        (path,) = tmp_path.rglob("*.xplane.pb")
+        spans = {}
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in SPANS:
+                        stats = dict(e.stats)
+                        spans.setdefault(stats["batch_id"], []).append(
+                            (e.start_ns, e.name, stats))
+        assert sorted(spans) == [0, 1, 2]
+        for bid, events in spans.items():
+            events.sort(key=lambda ev: ev[0])
+            assert [name for _, name, _ in events] == list(SPANS), bid
+        packs = [next(st for _, n, st in spans[b] if n == SPANS[0])
+                 for b in (0, 1, 2)]
+        assert [(p["bucket"], p["n_requests"]) for p in packs] == [
+            (4, 1), (4, 1), (8, 1)]
+
+
 class TestExecutorStageTiming:
     def test_stage_histograms_under_virtual_clock_replay(self):
         """The per-stage pin (ISSUE 13): drive the executor from a
@@ -241,11 +292,51 @@ class TestExecutorStageTiming:
         assert total("queue_wait") == 24 and total("e2e") == 24
         assert total("dispatch_ready") == st.batches
         assert total("batch_build") == st.batches
+        assert total("window_wait") == st.batches
         assert total("staging") == st.batches
         assert total("demux") == st.batches
         # e2e contains dispatch_ready by construction
         assert (st.stage_p50_ms["e2e"]
                 >= st.stage_p50_ms["dispatch_ready"])
+
+    def test_window_wait_under_virtual_clock(self):
+        """window_wait is packed -> staging start, read off the virtual
+        clock: with one in-flight slot, the second batch waits out the
+        first, and the clock the test advances meanwhile is its wait."""
+        now = [0.0]
+        release = threading.Event()
+
+        class Gated:
+            """A dispatch output the test holds unready."""
+
+            def __init__(self, value):
+                self.value = value
+
+            def is_ready(self):
+                return release.is_set()
+
+        reg = MetricRegistry()
+        ex = ServingExecutor(lambda b, **_: Gated(_host_dispatch(b)), (4,),
+                             dim=D, flush_age_s=0.0, max_in_flight=1,
+                             registry=reg, clock=lambda: now[0],
+                             name="windowtest")
+        try:
+            f0 = ex.submit(np.ones((2, D), np.float32))
+            _wait_for(lambda: ex.stats().in_flight == 1)
+            f1 = ex.submit(np.ones((3, D), np.float32))
+            _wait_for(lambda: ex.stats().pending == 0)
+            time.sleep(0.1)          # the batcher is in the window wait
+            now[0] = 2.5
+            release.set()
+            f0.result(timeout=30)
+            f1.result(timeout=30)
+        finally:
+            ex.close()
+        h = reg.histogram("serving_stage_ms", executor="windowtest",
+                          stage="window_wait", bucket=4)
+        assert h.count == 2
+        assert h.sum == pytest.approx(2500.0)
+        assert ex.stats().stage_p99_ms["window_wait"] >= 1000.0
 
     def test_executor_stats_positional_compat(self):
         """The pre-r13 12-field positional construction still works and
@@ -538,11 +629,17 @@ class TestAnnotateGate:
             annotate_mod.push_range("hot %d", 1)
             assert annotate_mod._stack == []
             assert constructed == []
-            with annotate_mod.annotate("hot"):
+            with annotate_mod.annotate("hot", batch_id=1):
                 pass
             assert constructed == []
             # pop on the empty stack: loud no-op, never an exception
             annotate_mod.pop_range()
+            # the serving hot path: a served batch constructs no span
+            with ServingExecutor(_host_dispatch, (4,), dim=D,
+                                 flush_age_s=0.0,
+                                 registry=MetricRegistry()) as ex:
+                ex.submit(np.ones((2, D), np.float32)).result(timeout=30)
+            assert constructed == []
         finally:
             annotate_mod.set_profiling(prev)
 
@@ -570,6 +667,67 @@ class TestAnnotateGate:
             assert annotate_mod._stack == []
         finally:
             annotate_mod.set_profiling(prev)
+
+    def test_enabled_annotate_carries_stats(self, monkeypatch):
+        """A range's keyword stats ride on its TraceAnnotation; the
+        range yields it, so stats known only inside can be added."""
+        constructed = []
+
+        class Spy:
+            def __init__(self, label, **stats):
+                constructed.append((label, stats))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(annotate_mod.jax.profiler,
+                            "TraceAnnotation", Spy)
+        prev = annotate_mod.set_profiling(True)
+        try:
+            with annotate_mod.annotate("batch %d", 3, batch_id=3) as sp:
+                assert isinstance(sp, Spy)
+            assert constructed == [("batch 3", {"batch_id": 3})]
+        finally:
+            annotate_mod.set_profiling(prev)
+
+    def test_start_trace_passes_profiler_options(self, monkeypatch):
+        seen = []
+        monkeypatch.setattr(annotate_mod.jax.profiler, "start_trace",
+                            lambda d, **kw: seen.append((d, kw)))
+        monkeypatch.setattr(annotate_mod.jax.profiler, "stop_trace",
+                            lambda: None)
+        opts = object()
+        prev = annotate_mod.set_profiling(False)
+        try:
+            annotate_mod.start_trace("/tmp/t", opts)
+            annotate_mod.stop_trace()
+            annotate_mod.start_trace("/tmp/u")
+            annotate_mod.stop_trace()
+        finally:
+            annotate_mod.set_profiling(prev)
+        assert seen == [("/tmp/t", {"profiler_options": opts}),
+                        ("/tmp/u", {})]
+
+    def test_host_ranges_leave_programs_untouched(self, monkeypatch):
+        """A range open while a program is traced must not stamp its
+        label into the program's op metadata (a compile inside a
+        capture would differ from one outside it)."""
+        import jax
+
+        monkeypatch.setattr(annotate_mod.jax.profiler,
+                            "TraceAnnotation",
+                            lambda label, **kw: contextlib.nullcontext())
+        prev = annotate_mod.set_profiling(True)
+        try:
+            with annotate_mod.annotate("host.range"):
+                text = jax.jit(lambda x: x * 2).lower(
+                    np.ones(3, np.float32)).as_text(debug_info=True)
+        finally:
+            annotate_mod.set_profiling(prev)
+        assert "host.range" not in text
 
     def test_trace_capture_flips_the_gate(self, monkeypatch):
         monkeypatch.setattr(annotate_mod.jax.profiler, "start_trace",
