@@ -64,6 +64,7 @@ from raft_tpu.spatial.ann.common import (
     build_coarse_index,
     coarse_probe,
     n_super_probes,
+    place_row_store,
     resolve_qcap_arg,
     two_level_probe,
 )
@@ -1095,6 +1096,8 @@ def place_index(comms: Comms, index, *,
                 v = jax.device_put(
                     v, field_sharding(comms, f.name, np.ndim(v))
                 )
+                if f.name == getattr(index, "ROW_STORE", None):
+                    v = place_row_store(v)
         kw[f.name] = v
     return type(index)(**kw)
 

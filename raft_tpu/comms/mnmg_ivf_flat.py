@@ -95,6 +95,10 @@ class MnmgIVFFlatIndex:
     names shared with it so placement/serialization machinery applies
     unchanged)."""
 
+    # the row store each shard's grouped scan reads: place_index keeps
+    # it row-major, in its sharding (spatial/ann/common.place_row_store)
+    ROW_STORE: typing.ClassVar[str] = "vectors_sorted"
+
     centroids: jax.Array       # (n_lists_g, d) replicated
     owner: jax.Array           # (n_lists_g,) int32 — owning rank per list
     local_id: jax.Array        # (n_lists_g,) int32 — list id on its owner

@@ -41,6 +41,7 @@ __all__ = [
     "pure_callback",
     "io_callback",
     "compilation_cache_reset",
+    "persistent_cache_min_compile_time",
 ]
 
 
@@ -125,6 +126,16 @@ COMPAT_TABLE: Tuple[CompatEntry, ...] = (
                "has one home",
     ),
     CompatEntry(
+        name="persistent_cache_min_compile_time",
+        candidates=("jax._src.config.persistent_cache_min_compile_time_secs",),
+        banned=(
+            "jax._src.config.persistent_cache_min_compile_time_secs",
+        ),
+        reason="a thread-local context for the persistent cache's write "
+               "threshold has no public spelling; keep one program out of "
+               "the cache without touching the process-wide setting",
+    ),
+    CompatEntry(
         name="io_callback",
         candidates=("jax.experimental.io_callback",),
         banned=(
@@ -207,3 +218,6 @@ register_dataclass: Callable = resolve("register_dataclass")
 pure_callback: Callable = resolve("pure_callback")
 io_callback: Callable = resolve("io_callback")
 compilation_cache_reset: Callable = resolve("compilation_cache_reset")
+persistent_cache_min_compile_time: Callable = resolve(
+    "persistent_cache_min_compile_time"
+)

@@ -28,6 +28,8 @@ from raft_tpu.cluster.kmeans import KMeansParams, kmeans_fit
 from raft_tpu.spatial.ann.common import (
     ListStorage,
     build_list_storage,
+    place_row_store,
+    set_row_store_gauge,
     split_oversized_lists,
 )
 
@@ -98,9 +100,14 @@ class IVFFlatIndex:
         docs/static_analysis.md "Two tiers") and raises listing the
         findings if it violates the serving-tier invariants — the
         in-process spot check of the CI gate ``ci/run.sh programs``.
+
+        Sets the ``ivf_row_store_row_major`` gauge (``engine="flat"``) to
+        1 when the warmed program takes the row store row-major, as
+        :func:`...common.place_row_store` leaves it at build.
         """
         from raft_tpu.spatial.ann.common import static_qcap
 
+        set_row_store_gauge("flat", self.data_sorted)
         qc = static_qcap(qcap, nq, n_probes, self.centroids.shape[0])
         q0 = jnp.zeros((nq, self.centroids.shape[1]), jnp.float32)
         out = ivf_flat_search_grouped(
@@ -153,9 +160,9 @@ def ivf_flat_build(x, params: IVFFlatParams = IVFFlatParams(), *,
             labels_np, cents, params.max_list_cap
         )
     storage = build_list_storage(labels_np, cents.shape[0])
-    data_sorted = jnp.concatenate(
+    data_sorted = place_row_store(jnp.concatenate(
         [x[storage.sorted_ids], jnp.zeros((1, x.shape[1]), x.dtype)]
-    )
+    ))
     return IVFFlatIndex(cents, data_sorted, storage, metric)
 
 

@@ -31,6 +31,7 @@ from raft_tpu.cluster.kmeans import KMeansParams, kmeans_fit
 from raft_tpu.spatial.ann.common import (
     ListStorage,
     build_list_storage,
+    place_row_store,
     split_oversized_lists,
 )
 
@@ -103,9 +104,14 @@ class IVFSQIndex:
         value is returned; pass exactly that integer on every serving
         dispatch (docs/serving.md). ``audit=True`` runs the jaxpr-level
         program auditor over the warmed program and raises on findings
-        (:mod:`raft_tpu.analysis.program`; see IVFFlatIndex.warmup)."""
-        from raft_tpu.spatial.ann.common import static_qcap
+        (:mod:`raft_tpu.analysis.program`; see IVFFlatIndex.warmup).
+        Sets the ``ivf_row_store_row_major`` gauge (``engine="sq"``) off
+        the code store's layout, as the flat warmup does."""
+        from raft_tpu.spatial.ann.common import (
+            set_row_store_gauge, static_qcap,
+        )
 
+        set_row_store_gauge("sq", self.codes_sorted)
         qc = static_qcap(qcap, nq, n_probes, self.centroids.shape[0])
         q0 = jnp.zeros((nq, self.centroids.shape[1]), jnp.float32)
         out = ivf_sq_search_grouped(
@@ -157,9 +163,9 @@ def ivf_sq_build(x, params: IVFSQParams = IVFSQParams()) -> IVFSQIndex:
             labels_np, cents, params.max_list_cap
         )
     storage = build_list_storage(labels_np, cents.shape[0])
-    codes_sorted = jnp.concatenate(
+    codes_sorted = place_row_store(jnp.concatenate(
         [codes[storage.sorted_ids], jnp.zeros((1, x.shape[1]), jnp.int8)]
-    )
+    ))
     return IVFSQIndex(cents, codes_sorted, vmin, vscale, storage)
 
 

@@ -60,6 +60,7 @@ from raft_tpu.spatial.ann.common import (
     ListStorage,
     build_list_storage,
     coarse_probe,
+    place_row_store,
     static_qcap,
 )
 from raft_tpu.spatial.ann.ivf_flat import (
@@ -974,9 +975,9 @@ def compact(
     if engine == "flat":
         new_index = IVFFlatIndex(
             centroids=jnp.asarray(cents_new),
-            data_sorted=jnp.asarray(rows_sorted.astype(
+            data_sorted=place_row_store(jnp.asarray(rows_sorted.astype(
                 np.asarray(index.data_sorted).dtype
-            )),
+            ))),
             storage=st,
             metric=index.metric,
         )
@@ -998,7 +999,7 @@ def compact(
         ])
         new_index = IVFSQIndex(
             centroids=jnp.asarray(cents_new),
-            codes_sorted=jnp.asarray(codes_new),
+            codes_sorted=place_row_store(jnp.asarray(codes_new)),
             vmin=index.vmin,
             vscale=index.vscale,
             storage=st,

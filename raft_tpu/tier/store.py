@@ -75,7 +75,9 @@ from jax import lax
 from raft_tpu import errors
 from raft_tpu.analysis.threads import runtime as lockcheck
 from raft_tpu.obs import metrics as obs_metrics
-from raft_tpu.spatial.ann.common import ListStorage, static_qcap
+from raft_tpu.spatial.ann.common import (
+    ListStorage, place_row_store, static_qcap,
+)
 from raft_tpu.spatial.ann.ivf_flat import IVFFlatIndex, _grouped_impl
 
 __all__ = ["TierRuntime", "TierStats", "TieredListStore"]
@@ -227,8 +229,10 @@ class TieredListStore:
 
         # -- device state (every array a runtime operand) --------------
         self._cents_dev = jnp.asarray(base.centroids)
-        self._hot_data = jnp.zeros((self._n_view + 1, self._d),
-                                   self._data_np.dtype)
+        # the grouped scan reads the hot buffer as its row store: keep it
+        # row-major (common.place_row_store), here and after each install
+        self._hot_data = place_row_store(jnp.zeros(
+            (self._n_view + 1, self._d), self._data_np.dtype))
         self._hot_ids = jnp.full((self._n_view,), -1, jnp.int32)
         # only ``.shape[0]`` of list_index is read by the grouped scan
         self._dummy_index = jnp.zeros((self._n_lists, 1), jnp.int32)
@@ -700,7 +704,8 @@ class TieredListStore:
         row0 = jnp.int32(slot * self._L)
         with self._lock:
             cur_data, cur_ids = self._hot_data, self._hot_ids
-        new_data = _install_rows(cur_data, dev_slab, row0)
+        # the install program's output takes the device's default layout
+        new_data = place_row_store(_install_rows(cur_data, dev_slab, row0))
         new_ids = _install_ids(cur_ids, dev_ids, row0)
         ms = (self._clock() - t0) * 1e3
         if callable(busy):
