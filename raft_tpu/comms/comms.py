@@ -369,6 +369,16 @@ class Comms:
         """The device-side facade to close over inside shard_map."""
         return AxisComms(self.axis)
 
+    def replicate(self, x) -> jax.Array:
+        """``x`` placed whole on every device of the mesh. The ``stage``
+        of a :class:`~raft_tpu.serving.ServingExecutor` over a sharded
+        index: its search program reads the queries replicated, so a
+        batch staged here enters the program as it is, and a donated
+        one is consumed in place, with no reshard in the dispatch."""
+        return jax.device_put(
+            x, jax.sharding.NamedSharding(self.mesh,
+                                          jax.sharding.PartitionSpec()))
+
     def comm_split(self, colors: Sequence[int], keys: Optional[Sequence[int]] = None):
         """Partition ranks by color into sub-communicators
         (reference comms.hpp:189 / std_comms.hpp:144-180 ncclCommSplit-style).

@@ -240,14 +240,23 @@ def _cached_program(key, make):
     return fn
 
 
-def _slab_height(loads) -> int:
+def _slab_height(loads, max_list=None) -> int:
     """Bucketed per-rank slab height (n_pad) shared by the distributed
-    builds and :func:`reshard_index`: the raw max-load is data-dependent,
-    so a same-shape rebuild (or a reshard) would shift n_pad by a handful
-    of rows and recompile BOTH the assembly program and every search
-    program keyed on it; rounding up to a coarse bucket (<= ~6% slab
-    padding) keeps the statics — and the compiled programs — stable."""
-    raw_npad = max(int(np.max(loads)), 1)
+    builds, :func:`reshard_index` and :func:`replicate_index`. The raw
+    max-load is data-dependent, so a same-shape rebuild (or a reshard)
+    would shift n_pad by a handful of rows and recompile BOTH the
+    assembly program and every search program keyed on it. Given the
+    largest list ``max_list`` of an LPT placement (``_lpt_assign``), the
+    height is that placement's bound instead: a rank holds at most the
+    mean load plus one list. With a list cap, ``max_list`` is the cap as
+    soon as any list splits, so the height follows rows, cap and chips
+    alone. Either way it is rounded up to a coarse bucket (<= ~6% slab
+    padding), which keeps the statics — and the compiled programs —
+    stable."""
+    if max_list is None:
+        raw_npad = max(int(np.max(loads)), 1)
+    else:
+        raw_npad = _cdiv_host(int(np.sum(loads)), len(loads)) + int(max_list)
     bucket = 256 if raw_npad < (1 << 17) else 4096
     return _cdiv_host(raw_npad, bucket) * bucket
 
@@ -614,20 +623,29 @@ def _exchange_and_assemble(
         ssz = sizes
 
     owner, local_id, loads, lists_per = _lpt_assign(ssz, Pn)
-    n_pad = _slab_height(loads)
-    nl_pad = int(lists_per.max()) + 1          # +1 empty sentinel list
     max_list = max(int(ssz.max()), 1)
+    n_pad = _slab_height(loads, max_list)
+    nl_pad = int(lists_per.max()) + 1          # +1 empty sentinel list
     offs_sh, szs_sh, lcents_sh = _rank_slab_maps(
         owner, local_id, ssz, cents_np, Pn, nl_pad, d
     )
+    # each list's owner and first slab position there, padded to the
+    # most lists the split can make (each split sublist holds ``cap``
+    # rows, so at most n / cap of them are added), so that the route
+    # program's shapes follow rows, lists and cap alone
+    n_lists_max = nl + (n // cap if cap else 0)
+    owner_in = np.pad(owner, (0, n_lists_max - owner.shape[0]))
+    first_in = np.pad(offs_sh[owner, local_id],
+                      (0, n_lists_max - owner.shape[0]))
 
     # ---- phase 4a: device-side routing. Each row's GLOBAL within-list
     # rank (a per-rank prefix over the phase-2 count matrix + a local
     # stable sort) yields both its split sublist AND its exact slab
     # position on the destination rank — so the exchange below needs no
-    # receive-side sort and no global-max-padded buffers.
-    def route_body(lbl_sh, nv_sh, C_in, owner_in, lid_in, base_in,
-                   offs_in):
+    # receive-side sort and no global-max-padded buffers. The rows in
+    # destination order (``dorder``) let the exchange fill each send
+    # slot by a gather.
+    def route_body(lbl_sh, nv_sh, C_in, owner_in, first_in, base_in):
         lbl, nvr = lbl_sh[0], nv_sh[0]
         valid = jnp.arange(nloc, dtype=jnp.int32) < nvr
         starts = (jnp.cumsum(C_in, axis=0) - C_in)[ax.get_rank()]
@@ -649,32 +667,23 @@ def _exchange_and_assemble(
             wsub = gw % cap                # rank within the split sublist
         else:
             nlbl, wsub = lbl, gw
-        lloc = lid_in[nlbl]
         dest = jnp.where(valid, owner_in[nlbl], Pn)          # Pn = dropped
         # destination slab position: owner-local list offset + sublist rank
-        pos = offs_in[jnp.minimum(dest, Pn - 1), lloc] + wsub
-        # send-slot index: this row's rank among rows bound for its dest
-        dorder = jnp.argsort(dest, stable=True)
-        dsort = dest[dorder]
-        dstart = jnp.searchsorted(
-            dsort, jnp.arange(Pn, dtype=jnp.int32)
-        ).astype(jnp.int32)
-        wdsort = (
-            jnp.arange(nloc, dtype=jnp.int32)
-            - dstart[jnp.minimum(dsort, Pn - 1)]
-        )
-        wslot = jnp.zeros((nloc,), jnp.int32).at[dorder].set(wdsort)
+        pos = first_in[nlbl] + wsub
+        # the rows bound for each dest, contiguous and in row order:
+        # send slot w to dest p is row dorder[sum(dcnt[:p]) + w]
+        dorder = jnp.argsort(dest, stable=True).astype(jnp.int32)
         dcnt = jnp.zeros((Pn + 1,), jnp.int32).at[dest].add(1)[:Pn]
-        return dest[None], pos[None], wslot[None], ax.allgather(dcnt)
+        return pos[None], dorder[None], ax.allgather(dcnt)
 
-    dest_g, pos_g, wslot_g, C2 = _cached_program(
+    pos_g, dorder_g, C2 = _cached_program(
         ("route", comms.mesh, comms.axis, Pn, nloc, nl, cap,
-         owner.shape[0], offs_sh.shape[1]),
+         n_lists_max),
         lambda: jax.jit(comms.shard_map(
-            route_body, in_specs=(sh2, sh1, rep, rep, rep, rep, rep),
-            out_specs=(sh2, sh2, sh2, rep),
+            route_body, in_specs=(sh2, sh1, rep, rep, rep, rep),
+            out_specs=(sh2, sh2, rep),
         )),
-    )(lbl_g, n_valid, C, owner, local_id, base_np, offs_sh)
+    )(lbl_g, n_valid, C, owner_in, first_in, base_np)
     C2_np = np.asarray(C2)                                   # (src, dst)
     max_send = max(1, int(C2_np.max()))
 
@@ -683,8 +692,10 @@ def _exchange_and_assemble(
     # ~half a shard of rows — regardless of P (incl. the 1-device shard
     # program) and of skewed locality concentrating one source's rows on
     # one owner, where a single global-max-padded exchange would allocate
-    # P x shard and OOM at the DEEP-100M shard shape.
-    ms_r = min(max_send, max(1024, _cdiv_host(max(nloc, 1), 2 * Pn)))
+    # P x shard and OOM at the DEEP-100M shard shape. ``ms_r`` follows
+    # the shape alone and the rounds run from the host, so the round
+    # programs compile once per shape, whatever the data sends where.
+    ms_r = min(max(nloc, 1), max(1024, _cdiv_host(max(nloc, 1), 2 * Pn)))
     n_rounds = _cdiv_host(max_send, ms_r)
     gb_np = np.concatenate([[0], np.cumsum(n_valid)[:-1]]).astype(np.int32)
     with_codes = codes_g is not None
@@ -692,89 +703,103 @@ def _exchange_and_assemble(
         codes_g if with_codes
         else jnp.zeros((Pn, 1, 1), jnp.uint8)   # unused placeholder
     )
+    # send slot j of dest p is flat slot p * ms_r + j: every buffer is
+    # (P * ms_r, ...), viewed as (P, ms_r, ...) across the all_to_all
+    n_slot = Pn * ms_r
 
-    def asm_body(x_sh, codes_sh, dest_sh, pos_sh, wslot_sh, gb_sh, C2_in):
+    def swap(buf):
+        """``buf``'s ms_r-row chunk p sent to rank p; chunk s of the
+        result came from rank s."""
+        return ax.alltoall(buf.reshape((Pn, ms_r) + buf.shape[1:])) \
+            .reshape(buf.shape)
+
+    def send_body(x_sh, codes_sh, pos_sh, dorder_sh, gb_sh, C2_in, t_in,
+                  sids_sh):
+        """Round ``t``: each dest's next ``ms_r`` rows. Their ids and slab
+        positions are exchanged and the ids scattered into the id slab
+        here; the row (and code) payloads are gathered into the send
+        buffers, with the slab positions their receiver puts them at
+        (``n_pad + 1``, out of the slab, for empty slots)."""
         xb, cds = x_sh[0], codes_sh[0]
-        dst, pos, wslot, gb = (
-            dest_sh[0], pos_sh[0], wslot_sh[0], gb_sh[0]
-        )
+        pos, dorder, gb = pos_sh[0], dorder_sh[0], gb_sh[0]
         me = ax.get_rank()
-        gids = gb + jnp.arange(nloc, dtype=jnp.int32)
-
-        def round_t(t, slabs):
-            codes_sl, sids_sl, vecs_sl = slabs
-            w0 = t * ms_r
-            in_r = (wslot >= w0) & (wslot < w0 + ms_r) & (dst < Pn)
-            dsel = jnp.where(in_r, dst, Pn)                  # Pn drops
-            wr = jnp.where(in_r, wslot - w0, 0)
-
-            def ex(payload, dtype):
-                buf = jnp.zeros((Pn, ms_r) + payload.shape[1:], dtype)
-                buf = buf.at[dsel, wr].set(
-                    payload.astype(dtype), mode="drop"
-                )
-                return ax.alltoall(buf)                      # [s] = from s
-
-            rb_gid = ex(gids, jnp.int32)
-            rb_pos = ex(pos, jnp.int32)
-            valid_r = (
-                w0 + jnp.arange(ms_r, dtype=jnp.int32)[None, :]
-                < C2_in[:, me][:, None]
-            )
-            pc = jnp.where(valid_r, rb_pos, n_pad + 1).reshape(-1)
-            ps = jnp.where(valid_r, rb_pos, n_pad).reshape(-1)
-            if with_codes:
-                rb_codes = ex(cds, jnp.uint8)                # (P, ms_r, M)
-                codes_sl = codes_sl.at[pc].set(
-                    rb_codes.reshape(-1, M), mode="drop"
-                )
-            sids_sl = sids_sl.at[ps].set(rb_gid.reshape(-1), mode="drop")
-            if store_vectors:
-                rb_vec = ex(xb, xb.dtype)                    # (P, ms_r, d)
-                vecs_sl = vecs_sl.at[pc].set(
-                    rb_vec.reshape(-1, d), mode="drop"
-                )
-            return (codes_sl, sids_sl, vecs_sl)
-
-        slabs0 = (
-            jnp.zeros((n_pad + 1, M) if with_codes else (1, 1), jnp.uint8),
-            jnp.zeros((n_pad,), jnp.int32),
-            jnp.zeros(
-                (n_pad + 1, d) if store_vectors else (1, d), xb.dtype
-            ),
-        )
-        codes_out, sids_out, vecs_out = lax.fori_loop(
-            0, n_rounds, round_t, slabs0
-        )
-        outs = [sids_out[None]]
+        # where each dest's rows start in dorder
+        dstart = jnp.cumsum(C2_in[me]) - C2_in[me]
+        slot = jnp.arange(n_slot, dtype=jnp.int32)
+        slot_p, slot_j = slot // ms_r, slot % ms_r
+        w0 = t_in * ms_r
+        # the row in each send slot: a gather, since a scatter of n_loc
+        # rows into the send buffer compiles ~10x slower on the TPU;
+        # slots past a dest's count carry rows the receiver drops
+        src = dorder[jnp.minimum(dstart[slot_p] + w0 + slot_j, nloc - 1)]
+        rb_gid = swap(gb + src)                              # [s] = from s
+        rb_pos = swap(pos[src])
+        valid_r = w0 + slot_j < C2_in[slot_p, me]
+        sids = sids_sh[0].at[jnp.where(valid_r, rb_pos, n_pad)].set(
+            rb_gid, mode="drop")
+        outs = [sids[None], jnp.where(valid_r, rb_pos, n_pad + 1)[None]]
         if with_codes:
-            outs.append(codes_out[None])
+            outs.append(jnp.take(cds, src, axis=0)[None])
         if store_vectors:
-            outs.append(vecs_out[None])
+            outs.append(jnp.take(xb, src, axis=0)[None])
         return tuple(outs)
 
-    out_specs = (
-        (sh2,) + ((sh3,) if with_codes else ())
-        + ((sh3,) if store_vectors else ())
-    )
-    res = _cached_program(
-        # keyed on (ms_r, n_rounds), NOT raw max_send: the body only
-        # depends on the round geometry, and max_send shifts by a few
-        # rows between same-shape rebuilds
-        ("asm", comms.mesh, comms.axis, Pn, nloc, d, M, ms_r,
-         n_rounds, n_pad, with_codes, store_vectors, str(x.dtype)),
+    # the payloads' exchange is a program of its own: beside the gather
+    # that fills it or the scatter that empties it, an all_to_all of
+    # (P * ms_r, d) rows compiles for minutes on the TPU, alone in seconds
+    def swap_body(buf_sh):
+        return swap(buf_sh[0])[None]
+
+    def recv_body(slab_sh, pc_sh, rb_sh):
+        return slab_sh[0].at[pc_sh[0]].set(rb_sh[0], mode="drop")[None]
+
+    def placed(shape, dtype):
+        """Zeros sharded over the mesh, made on the devices."""
+        sharding = NamedSharding(
+            comms.mesh, P(comms.axis, *([None] * (len(shape) - 1))))
+        return _cached_program(
+            ("zeros", sharding, shape, str(dtype)),
+            lambda: jax.jit(lambda: jnp.zeros(shape, dtype),
+                            out_shardings=sharding),
+        )()
+
+    payloads = [name for name, on in (("codes", with_codes),
+                                      ("vecs", store_vectors)) if on]
+    send = _cached_program(
+        ("send", comms.mesh, comms.axis, Pn, nloc, d, M, ms_r, n_pad,
+         with_codes, store_vectors, str(x.dtype)),
         lambda: jax.jit(comms.shard_map(
-            asm_body, in_specs=(sh3, sh3, sh2, sh2, sh2, sh1, rep),
-            out_specs=out_specs,
-        )),
-    )(x, codes_in, dest_g, pos_g, wslot_g, gb_np, C2)
-    slabs = {"sids": res[0]}
-    i = 1
+            send_body, in_specs=(sh3, sh3, sh2, sh2, sh1, rep, rep, sh2),
+            out_specs=(sh2, sh2) + (sh3,) * len(payloads),
+        ), donate_argnums=(7,)),
+    )
+
+    def exchange(slab, pc, buf):
+        """Scatter ``buf``'s rows, swapped over the mesh, into ``slab``
+        (donated) at ``pc``."""
+        key = (comms.mesh, comms.axis, buf.shape, str(buf.dtype))
+        swapped = _cached_program(("swap",) + key, lambda: jax.jit(
+            comms.shard_map(swap_body, in_specs=(sh3,), out_specs=sh3),
+            donate_argnums=(0,),
+        ))(buf)
+        return _cached_program(("recv", slab.shape) + key, lambda: jax.jit(
+            comms.shard_map(recv_body, in_specs=(sh3, sh2, sh3),
+                            out_specs=sh3),
+            donate_argnums=(0,),
+        ))(slab, pc, swapped)
+
+    slabs = {"sids": placed((Pn, n_pad), jnp.int32)}
     if with_codes:
-        slabs["codes"] = res[i]
-        i += 1
+        slabs["codes"] = placed((Pn, n_pad + 1, M), jnp.uint8)
     if store_vectors:
-        slabs["vecs"] = res[i]
+        slabs["vecs"] = placed((Pn, n_pad + 1, d), x.dtype)
+    for t in range(n_rounds):
+        slabs["sids"], pc, *bufs = send(
+            x, codes_in, pos_g, dorder_g, gb_np, C2, np.int32(t),
+            slabs["sids"])
+        for name, buf in zip(payloads, bufs):
+            slabs[name] = exchange(slabs[name], pc, buf)
+        del pc, bufs
 
     maps = {
         "cents_np": cents_np,
@@ -861,7 +886,7 @@ def reshard_index(comms: Comms, index, *, replication: int = 1,
     new_lid[real] = l_r
     # the build's shared layout helpers: identical bucketing and slab
     # geometry, so statics stay stable across repeated reshards
-    n_pad = _slab_height(loads)
+    n_pad = _slab_height(loads, index.max_list)
     nl_pad = int(lists_per.max()) + 1          # +1 empty sentinel list
     offs_sh, szs_sh, lcents_sh = _rank_slab_maps(
         new_owner, new_lid, sizes, cents, Pn, nl_pad, d

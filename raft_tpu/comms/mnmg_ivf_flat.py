@@ -80,11 +80,23 @@ from raft_tpu.spatial.ann.ivf_flat import (
 )
 
 __all__ = [
-    "MnmgIVFFlatIndex", "MnmgIVFSQIndex", "mnmg_ivf_flat_build",
+    "MNMG_SCOPES", "MnmgIVFFlatIndex", "MnmgIVFSQIndex",
+    "PROGRAM_NAMES", "mnmg_ivf_flat_build",
     "mnmg_ivf_flat_build_distributed", "mnmg_ivf_flat_search",
     "mnmg_ivf_sq_build", "mnmg_ivf_sq_build_distributed",
     "mnmg_ivf_sq_search",
 ]
+
+# the named parts the sharded search program adds around the shared
+# grouped body, whose ``ivf.*`` scopes stay inside it (``jax.named_scope``
+# names; docs/observability.md "Spans and scopes")
+SCOPE_PROBE = "mnmg.probe"   # global coarse probe, owner and sentinel maps
+SCOPE_MERGE = "mnmg.merge"   # cross-shard all-gather + select_k
+MNMG_SCOPES = (SCOPE_PROBE, SCOPE_MERGE)
+# the jitted mesh programs' names, by ``sq``: a profile's "XLA Modules"
+# line shows each as ``jit_<name>``, apart from the single-chip
+# ``jit__grouped_impl`` that runs inside them
+PROGRAM_NAMES = {False: "_mnmg_flat_search", True: "_mnmg_sq_search"}
 
 
 @compat.register_dataclass
@@ -364,68 +376,70 @@ def _cached_search(
         if mutation:
             # mutation-tier runtime inputs (comms/mnmg_mutation.py)
             rm_s, dv_s, di_s = rest
-        lcents, vecs, sids = lcents[0], vecs_s[0], sids[0]
-        loffs, lszs = loffs[0], lszs[0]
-        rank = lax.axis_index(ax.axis)
+        with jax.named_scope(SCOPE_PROBE):
+            lcents, vecs, sids = lcents[0], vecs_s[0], sids[0]
+            loffs, lszs = loffs[0], lszs[0]
+            row_mask = rm_s[0] if mutation else None
+            rank = lax.axis_index(ax.axis)
+            qf = q.astype(jnp.float32)
+            row_valid = None
+            if degraded:
+                qf, row_valid = sanitize_query_rows(qf)
+            # replicated compute: identical global probes on every chip
+            if use_coarse:
+                # use_pallas (the shard-local scan-engine static)
+                # also kernelizes the probe stage through the shared
+                # core — neither probe tile materializes inside the
+                # fused program (auto-degrades to the legacy probe when
+                # the probe geometry does not fit the plan)
+                probes_g, _ = two_level_probe(
+                    qf, sup_c, mem_i, cpad, owner.shape[0], n_probes,
+                    n_super_probes(n_probes, sup_c.shape[0], overprobe),
+                    _PROBE_BLOCK_Q, use_pallas=use_pallas,
+                    pallas_interpret=pallas_interpret,
+                )
+            else:
+                probes_g, _ = coarse_probe(qf, cents, n_probes)
+            probe_owner = owner[probes_g]                    # (nq, p)
+            if degraded:
+                # replica-aware routing (see the PQ engine body):
+                # route[s] selects the copy serving shard s — a runtime
+                # input, so failover flips never retrace
+                j = route[jnp.clip(probe_owner, 0, n_ranks - 1)]
+                serving = jnp.where(
+                    (probe_owner >= 0) & (j >= 0),
+                    (probe_owner + jnp.maximum(j, 0) * replica_offset)
+                    % n_ranks,
+                    -1,
+                )                        # (nq, p) serving rank | -1
+                own = serving == rank
+                nlp_base = nl_pad // replication
+                lp = jnp.where(
+                    own,
+                    jnp.maximum(j, 0) * nlp_base + local_id[probes_g],
+                    jnp.int32(nl_pad - 1),                   # sentinel
+                )
+            else:
+                serving = probe_owner
+                own = probe_owner == rank
+                lp = jnp.where(
+                    own, local_id[probes_g],
+                    jnp.int32(nl_pad - 1),                   # sentinel
+                )
 
-        qf = q.astype(jnp.float32)
-        row_valid = None
-        if degraded:
-            qf, row_valid = sanitize_query_rows(qf)
-        # replicated compute: identical global probes on every chip
-        if use_coarse:
-            # use_pallas (the shard-local scan-engine static) also
-            # kernelizes the probe stage through the shared core —
-            # neither probe tile materializes inside the fused program
-            # (auto-degrades to the legacy probe when the probe
-            # geometry does not fit the plan)
-            probes_g, _ = two_level_probe(
-                qf, sup_c, mem_i, cpad, owner.shape[0], n_probes,
-                n_super_probes(n_probes, sup_c.shape[0], overprobe),
-                _PROBE_BLOCK_Q, use_pallas=use_pallas,
-                pallas_interpret=pallas_interpret,
+            storage = ListStorage(
+                sorted_ids=sids,
+                list_offsets=loffs,
+                # grouped search leaves list_index unused
+                list_index=jnp.zeros((nl_pad, 1), jnp.int32),
+                list_sizes=lszs,
+                n=n_pad,
+                max_list=max_list,
             )
-        else:
-            probes_g, _ = coarse_probe(qf, cents, n_probes)  # (nq, p)
-        probe_owner = owner[probes_g]                        # (nq, p)
-        if degraded:
-            # replica-aware routing (see the PQ engine body): route[s]
-            # selects the copy serving shard s — a runtime input, so
-            # failover flips never retrace
-            j = route[jnp.clip(probe_owner, 0, n_ranks - 1)]
-            serving = jnp.where(
-                (probe_owner >= 0) & (j >= 0),
-                (probe_owner + jnp.maximum(j, 0) * replica_offset)
-                % n_ranks,
-                -1,
-            )                                # (nq, p) serving rank | -1
-            own = serving == rank
-            nlp_base = nl_pad // replication
-            lp = jnp.where(
-                own,
-                jnp.maximum(j, 0) * nlp_base + local_id[probes_g],
-                jnp.int32(nl_pad - 1),                       # sentinel
+            shard = IVFFlatIndex(
+                centroids=lcents, data_sorted=vecs, storage=storage,
+                metric="sqeuclidean",  # sqrt applied after the merge
             )
-        else:
-            serving = probe_owner
-            own = probe_owner == rank
-            lp = jnp.where(
-                own, local_id[probes_g],
-                jnp.int32(nl_pad - 1),                       # sentinel
-            )
-
-        storage = ListStorage(
-            sorted_ids=sids,
-            list_offsets=loffs,
-            list_index=jnp.zeros((nl_pad, 1), jnp.int32),    # grouped unused
-            list_sizes=lszs,
-            n=n_pad,
-            max_list=max_list,
-        )
-        shard = IVFFlatIndex(
-            centroids=lcents, data_sorted=vecs, storage=storage,
-            metric="sqeuclidean",  # sqrt applied after the merge
-        )
         # the UNCHANGED single-chip grouped exact kernel, probes
         # pre-mapped to shard-local list ids; sorted_ids are global
         # (use_pallas routes the shard-local scan through the Pallas
@@ -433,34 +447,35 @@ def _cached_search(
         # docs/ivf_scale.md "Flat scan in VMEM")
         vals, gids = _grouped_impl(
             shard, qf, k, n_probes, qcap, list_block, probes=lp,
-            row_mask=rm_s[0] if mutation else None,
+            row_mask=row_mask,
             use_pallas=use_pallas, pallas_interpret=pallas_interpret,
             rerank_ratio=rerank_ratio, dequant=dequant,
         )
-        if mutation:
-            from raft_tpu.comms.mnmg_ivf import _merge_local_delta
+        with jax.named_scope(SCOPE_MERGE):
+            if mutation:
+                from raft_tpu.comms.mnmg_ivf import _merge_local_delta
 
-            vals, gids = _merge_local_delta(
-                qf, vals, gids, dv_s[0], di_s[0], k, rank, nl_pad,
-                replication, replica_offset, n_ranks, alive, route,
+                vals, gids = _merge_local_delta(
+                    qf, vals, gids, dv_s[0], di_s[0], k, rank, nl_pad,
+                    replication, replica_offset, n_ranks, alive, route,
+                )
+            if degraded:
+                # a down shard contributes +inf distances to the merge
+                vals = jnp.where(alive[rank] > 0, vals, jnp.inf)
+            # in-program cross-shard merge: flat allgather + select_k on
+            # a 1-level mesh (merge_ways pads to deployment width with
+            # +inf/-1 absent-peer payloads — identical results), the
+            # two-stage ICI x DCN merge on a 2-level mesh
+            # (docs/multihost.md)
+            md, mi = _merge_across_shards(
+                ax, hier, vals, gids, k, merge_ways, wire
             )
-        if degraded:
-            # a down shard contributes +inf distances to the merge
-            vals = jnp.where(alive[rank] > 0, vals, jnp.inf)
-        # in-program cross-shard merge: flat allgather + select_k on a
-        # 1-level mesh (merge_ways pads to deployment width with
-        # +inf/-1 absent-peer payloads — identical results), the
-        # two-stage ICI x DCN merge on a 2-level mesh
-        # (docs/multihost.md)
-        md, mi = _merge_across_shards(
-            ax, hier, vals, gids, k, merge_ways, wire
-        )
-        if degraded:
-            # a failed-over shard on a live replica counts covered
-            cov = probe_coverage(serving, alive, row_valid)
-            md, mi = mask_invalid_rows(md, mi, row_valid)
-            return md, mi, cov, row_valid
-        return md, mi
+            if degraded:
+                # a failed-over shard on a live replica counts covered
+                cov = probe_coverage(serving, alive, row_valid)
+                md, mi = mask_invalid_rows(md, mi, row_valid)
+                return md, mi, cov, row_valid
+            return md, mi
 
     sharded3 = P(comms.axis, None, None)
     sharded2 = P(comms.axis, None)
@@ -481,10 +496,16 @@ def _cached_search(
         # row_mask, delta_vecs, delta_ids — per-rank mutation slabs
         in_specs = in_specs + (sharded2, sharded3, sharded2)
     sm = comms.shard_map(body, in_specs=in_specs, out_specs=out_specs)
+
+    def program(*opnds):
+        return sm(*opnds)
+
+    # a fixed name, so that a profile tells this program's runs apart
+    program.__name__ = program.__qualname__ = PROGRAM_NAMES[sq]
     # queries are positional argument 8; the coarse arrays and, when
     # present, the alive mask + failover route and the mutation slabs
     # follow them (donation: serving mode)
-    return jax.jit(sm, donate_argnums=(8,) if donate else ())
+    return jax.jit(program, donate_argnums=(8,) if donate else ())
 
 
 def mnmg_ivf_flat_search(

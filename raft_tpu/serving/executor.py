@@ -223,7 +223,9 @@ class ServingExecutor:
     re-dispatch reuses the exact snapshot the primary saw.
 
     ``stage`` — host→device staging (default :func:`jax.device_put`);
-    override to pin placement. ``donate`` is the caller's contract
+    override to pin placement: a sharded index takes its batches
+    replicated on its mesh (:meth:`raft_tpu.comms.Comms.replicate`,
+    docs/serving.md). ``donate`` is the caller's contract
     with its dispatch closure; the executor always re-stages hedged
     batches from the host copy, so donation inside ``dispatch`` is
     safe.

@@ -1549,6 +1549,20 @@ def test_place_index_reshards_directly(comms8, dataset, flat_index):
     np.testing.assert_array_equal(np.asarray(i2), np.asarray(i8))
 
 
+def test_reshard_keeps_the_builds_slab_height(comms8):
+    """Build and reshard size the slab by one rule: a reshard onto a mesh
+    of the build's size gives the build's statics, so it compiles no
+    new search program. One list a rank, so a rank's load (its list) and
+    the placement's bound (the mean load plus the largest list) round to
+    different slab heights."""
+    x = np.random.default_rng(11).standard_normal((1600, 16)).astype(
+        np.float32)
+    built = mnmg_ivf_flat_build(comms8, x, FLAT_PARAMS)
+    same = reshard_index(comms8, built)
+    assert (same.n_pad, same.nl_pad, same.max_list) == (
+        built.n_pad, built.nl_pad, built.max_list)
+
+
 def test_reshard_rejects_ownerless_index(comms8, flat_index):
     import dataclasses as dc
 

@@ -2,21 +2,26 @@
 reduction reads them by (docs/observability.md "Spans and scopes").
 
 Every op the grouped IVF program (``ivf.*``; flat and SQ, XLA scan and
-Pallas kernel, materialized and streamed partials) and the fused
-brute-force program (``knn.*``; DMA rescore, gather rescore, tiled) are
-traced from must carry one of its module's scopes in its compiled HLO
-``op_name`` — so that a profile can sum device time per part after any
-refactor — and the benchmark's reduction must spell the span and scope
-names exactly as the program defines them.
+Pallas kernel, materialized and streamed partials), the list-sharded
+mesh program around it (``mnmg.*``) and the fused brute-force program
+(``knn.*``; DMA rescore, gather rescore, tiled) are traced from must
+carry one of its module's scopes in its compiled HLO ``op_name`` — so
+that a profile can sum device time per part after any refactor — and the
+benchmark's reduction must spell the span, scope and program names
+exactly as the program defines them.
 """
 
 import collections
 import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from raft_tpu.comms import (
+    build_comms, mnmg_ivf_flat, mnmg_ivf_flat_build_distributed, shard_rows,
+)
 from raft_tpu.distance.distance_type import DistanceType
 from raft_tpu.serving import executor
 from raft_tpu.spatial import fused_knn
@@ -96,6 +101,56 @@ def test_grouped_program_ops_carry_a_scope(dataset, flat_index, sq_index,
     assert set(counts) == set(ivf_flat.GROUPED_SCOPES), counts
 
 
+@pytest.fixture(scope="module")
+def mnmg_index(dataset):
+    comms = build_comms(jax.devices()[:4])
+    xg, n_valid = shard_rows(comms, dataset[0])
+    return comms, mnmg_ivf_flat_build_distributed(
+        comms, xg, IVFFlatParams(n_lists=16, kmeans_n_iters=2,
+                                 kmeans_init="random"),
+        n_valid=n_valid, metric="sqeuclidean")
+
+
+# ops the compiler makes in the mesh program's frames (the shard_map's
+# and the inlined grouped program's: loop plumbing, constants split into
+# broadcasts), which carry a frame's or an instruction's name and stand
+# behind no traced op
+_PLUMBING = ("constant", "parameter", "get-tuple-element", "tuple", "copy",
+             "broadcast")
+
+
+@pytest.mark.parametrize("use_pallas", [False, True],
+                         ids=["xla", "kernel"])
+def test_mesh_program_ops_carry_a_scope(dataset, mnmg_index, use_pallas):
+    """The sharded search program has its own fixed name, and each op
+    traced from its body carries an ``mnmg.*`` scope (its global probe
+    and cross-shard merge) or an ``ivf.*`` one (the grouped body it runs
+    on each shard)."""
+    comms, index = mnmg_index
+    fn, args, _ = mnmg_ivf_flat._prepare_flat_family(
+        comms, index, comms.replicate(dataset[1]), 4, sq=False,
+        n_probes=4, qcap=8, list_block=4, qcap_max_drop_frac=None,
+        donate_queries=False, shard_mask=None, failover=None,
+        overprobe=2.0, merge_ways=None, mutation=None, wire="bf16",
+        use_pallas=use_pallas, rerank_ratio=4.0)
+    text = fn.lower(*args).compile().as_text()
+    program = mnmg_ivf_flat.PROGRAM_NAMES[False]
+    assert text.startswith(f"HloModule jit_{program},"), text[:80]
+    scopes = mnmg_ivf_flat.MNMG_SCOPES + ivf_flat.GROUPED_SCOPES
+    seen, counts, bare = _scoped_ops(text, program, scopes)
+    assert seen > 100, seen
+    assert set(counts) == set(scopes), counts
+    frame = re.compile(rf"jit\({program}\)/shard_map"
+                       r"(/jit\(_grouped_impl\))?(/[a-z-]+\.\d+)?$")
+    assert all(frame.match(b) for b in bare), sorted(set(bare))[:10]
+    made = [line for line in text.splitlines()
+            if any(f'op_name="{b}"' in line for b in set(bare))]
+    kinds = {re.search(r" ([a-z-]+)\(", line.split(" = ", 1)[1]).group(1)
+             for line in made}
+    assert kinds <= set(_PLUMBING) | {"fusion"}, kinds
+    assert all("broadcast" in line for line in made if " fusion(" in line)
+
+
 KNN_VARIANTS = {
     "dma": (128, {}),
     "dma_tiled": (128, dict(rescore_rows=8)),
@@ -136,3 +191,22 @@ def test_benchmark_reads_the_program_names():
             == f"jit_{fused_knn._fused_l2_knn_impl.__name__}")
     assert all(s.startswith(program_trace.SERVING_PREFIX)
                for s in executor.SPANS)
+
+
+def test_benchmark_reads_the_mesh_program_by_its_name():
+    """The four-chip cell's device readers name the sharded program as
+    the program names itself."""
+    import importlib.util
+    from pathlib import Path
+
+    name = f"jit_{mnmg_ivf_flat.PROGRAM_NAMES[False]}"
+    metrics = Path(__file__).resolve().parents[1] / "benchmark" / "metrics"
+    readers = sorted(metrics.glob("mnmg.*.py"))
+    programs = set()
+    for path in readers:
+        spec = importlib.util.spec_from_file_location(path.stem, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        if hasattr(mod, "PROGRAM"):
+            programs.add(mod.PROGRAM)
+    assert len(readers) >= 3 and programs == {name}, programs
